@@ -138,6 +138,32 @@ def total_retraces() -> int:
 
 
 # ---------------------------------------------------------------------------
+# persistent compilation cache placement
+# ---------------------------------------------------------------------------
+
+#: Where JAX's persistent compilation cache lives when
+#: ``JAX_COMPILATION_CACHE_DIR`` is unset: one fixed directory inside the
+#: checkout (the path is part of the cache key, so it must never move).
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
+
+
+def use_compile_cache() -> str:
+    """Place JAX's persistent compilation cache and return its directory.
+
+    Entry points call this before anything compiles (never at import).  With
+    ``JAX_COMPILATION_CACHE_DIR`` set, JAX has already read it and nothing is
+    changed; otherwise the cache goes to ``COMPILE_CACHE_DIR``.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return COMPILE_CACHE_DIR
+
+
+# ---------------------------------------------------------------------------
 # stage timing / throughput counters
 # ---------------------------------------------------------------------------
 
@@ -423,26 +449,37 @@ def run_compress_stage(hbae_params: dict, bae_params: list,
     programs (latents stay on device between them), one download.
 
     Returns numpy ``(q_lh, [q_lb per stage], recon)``; ``recon`` is computed
-    by the same ``decode_backend`` program ``run_decompress_stage`` uses, so
-    the GAE encoder corrects exactly what the decoder will reproduce.
+    by the same ``decode_backend`` program ``run_decompress_stage_async``
+    runs on the same stripe shape, so the GAE encoder corrects exactly what
+    the decoder will reproduce.
     """
     return fetch_compress_stage(run_compress_stage_async(
         hbae_params, bae_params, hyperblocks, hb_bin, bae_bin))
 
 
-def run_decompress_stage(hbae_params: dict, bae_params: list,
-                         q_lh: np.ndarray, q_lbs: list, hb_bin: float,
-                         bae_bin: float) -> np.ndarray:
-    """Fused dequantize+decode back-end: one upload, one program, one
-    download."""
-    dec = _CACHE.get("decode_backend", _decode_backend)
-    dq_lh = jnp.asarray(_as_q32(q_lh))
-    dq_lbs = [jnp.asarray(_as_q32(q)) for q in q_lbs]
-    recon = np.asarray(jax.device_get(
-        dec(hbae_params, bae_params, dq_lh, dq_lbs, hb_bin, bae_bin)))
-    # device_get hands back a read-only view; callers (GAE correction)
-    # write into the reconstruction in place.
-    return recon if recon.flags.writeable else recon.copy()
+def run_decompress_stage_async(hbae_params: dict, bae_params: list,
+                               q_lh: np.ndarray, q_lbs: list, hb_bin: float,
+                               bae_bin: float, mesh=None) -> Array:
+    """Dispatch the fused dequantize+decode back-end on ONE stripe's
+    latents, or with a ``mesh`` on one shard group (``n_shards`` equal-width
+    stripes, one per shard), without blocking.  These are the programs and
+    shapes ``run_compress_stage*`` decoded the same stripes with, so the
+    reconstruction is bit-identical to the one the GAE encoder verified.
+    Returns the on-device reconstruction."""
+    q_lh = _as_q32(q_lh)
+    q_lbs = [_as_q32(q) for q in q_lbs]
+    if mesh is None:
+        dec = _CACHE.get("decode_backend", _decode_backend)
+        return dec(hbae_params, bae_params, jnp.asarray(q_lh),
+                   [jnp.asarray(q) for q in q_lbs], hb_bin, bae_bin)
+    from jax.sharding import PartitionSpec as P
+    shard = P(_mesh_axis())
+    dec = _sharded_program(
+        "decode_backend", _decode_backend, mesh,
+        (P(), P(), shard, shard, P(), P()), shard)
+    counter_max("mesh.shards", int(mesh.shape[_mesh_axis()]))
+    return dec(hbae_params, bae_params, put_sharded(q_lh, mesh),
+               [put_sharded(q, mesh) for q in q_lbs], hb_bin, bae_bin)
 
 
 def run_recon_stage(hbae_params: dict, bae_params: list,
@@ -475,12 +512,19 @@ def _sharded_program(name: str, fn: Callable, mesh, in_specs, out_specs
     """Build-or-fetch one shard_map-wrapped jitted program.  The retrace
     counter name carries the shard count so sharded and unsharded traces are
     distinguishable in ``retrace_counts()``."""
-    from jax.experimental.shard_map import shard_map
     axis = _mesh_axis()
     counted_name = f"{name}@{axis}{mesh.shape[axis]}"
-    wrapped = shard_map(fn, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, check_rep=False)
+    wrapped = jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                            out_specs=out_specs, check_vma=False)
     return _CACHE.get(counted_name, wrapped, mesh=mesh)
+
+
+def put_sharded(a: np.ndarray, mesh) -> Array:
+    """Upload ``a`` split over the mesh's hyper-block axis, each shard's rows
+    straight to its own device (no staging on the first device)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    return jax.device_put(np.asarray(a),
+                          NamedSharding(mesh, P(_mesh_axis())))
 
 
 def run_compress_stage_sharded_async(hbae_params: dict, bae_params: list,
@@ -502,7 +546,7 @@ def run_compress_stage_sharded_async(hbae_params: dict, bae_params: list,
     dec = _sharded_program(
         "decode_backend", _decode_backend, mesh,
         (P(), P(), shard, shard, P(), P()), shard)
-    x = jnp.asarray(stacked)
+    x = put_sharded(stacked, mesh)
     q_lh, q_lbs = enc(hbae_params, bae_params, x, hb_bin, bae_bin)
     recon = dec(hbae_params, bae_params, q_lh, q_lbs, hb_bin, bae_bin)
     return q_lh, q_lbs, recon
@@ -528,43 +572,3 @@ def run_compress_stage_sharded(hbae_params: dict, bae_params: list,
     counter_add("mesh.sharded_groups")
     return out
 
-
-def run_decompress_stage_sharded(hbae_params: dict, bae_params: list,
-                                 q_lh: np.ndarray, q_lbs: list,
-                                 hb_bin: float, bae_bin: float, mesh
-                                 ) -> np.ndarray:
-    """Fused dequantize+decode back-end over the mesh: hyper-block rows are
-    zero-padded to an even shard split (padded rows decode to garbage and
-    are sliced off; real rows decode shard-locally).  ``q_lbs`` rows group
-    ``k`` blocks per hyper-block, so their padded leading axes stay aligned
-    with ``q_lh``'s shard boundaries by construction.
-    """
-    from jax.sharding import PartitionSpec as P
-    axis = _mesh_axis()
-    n_shards = int(mesh.shape[axis])
-    q_lh = _as_q32(q_lh)
-    q_lbs = [_as_q32(q) for q in q_lbs]
-    n = q_lh.shape[0]
-    pad = (-n) % n_shards
-    if pad:
-        q_lh = np.concatenate(
-            [q_lh, np.zeros((pad,) + q_lh.shape[1:], q_lh.dtype)], axis=0)
-        padded_lbs = []
-        for q in q_lbs:
-            k = q.shape[0] // n
-            padded_lbs.append(np.concatenate(
-                [q, np.zeros((pad * k,) + q.shape[1:], q.dtype)], axis=0))
-        q_lbs = padded_lbs
-    shard = P(axis)
-    dec = _sharded_program(
-        "decode_backend", _decode_backend, mesh,
-        (P(), P(), shard, shard, P(), P()), shard)
-    t0 = time.perf_counter()
-    recon = np.asarray(jax.device_get(
-        dec(hbae_params, bae_params, jnp.asarray(q_lh),
-            [jnp.asarray(q) for q in q_lbs], hb_bin, bae_bin)))
-    recon = recon[:n]
-    record_stage("ae_decode_sharded", time.perf_counter() - t0,
-                 int(recon.size), calls=n_shards)
-    counter_max("mesh.shards", n_shards)
-    return recon if recon.flags.writeable else recon.copy()

@@ -205,19 +205,23 @@ def gae_encode_blocks(x: np.ndarray, x_r: np.ndarray, basis: np.ndarray,
                       max_refine: int = 20) -> tuple[np.ndarray, list[GAEBlockCode]]:
     """Encode every block with a HARD ||x - x^G||_2 <= tau guarantee.
 
-    Uses the one-shot vectorized selection, then verifies the realized error per
-    block against the *actual* reconstruction (guarding numerical non-
-    orthonormality of the eigh basis) and, for any block that cannot meet tau at
-    the global bin size, halves the bin (per-block ``bin_exp``) until it does.
-    With a full-rank basis the quantization error goes to 0 under refinement;
-    if the budget is exhausted with ``err > tau`` (rank-deficient basis,
-    ``max_refine`` too small), raises ``GuaranteeUnsatisfiable`` instead of
-    emitting a block that violates the bound the caller would then claim.
+    Uses the one-shot vectorized selection, then verifies each block against
+    the reconstruction the DECODER will compute from the emitted codes
+    (``gae_decode_blocks``' host float32 arithmetic, run here on the same
+    codes) — not against the selection's own ``corrected``, which on an
+    accelerator may come from a lower-precision matmul.  Blocks over ``tau``
+    are repaired in rounds: more coefficients first, then a halved bin (per-
+    block ``bin_exp``), each round re-decoding and re-verifying the whole
+    batch.  With a full-rank basis the quantization error goes to 0 under
+    refinement; if the budget is exhausted with ``err > tau`` (rank-deficient
+    basis, ``max_refine`` too small), raises ``GuaranteeUnsatisfiable``
+    instead of emitting a block that violates the bound the caller would then
+    claim.
 
-    Code construction is vectorized (errors, membership masks and the
-    ascending-index extraction are whole-batch numpy passes); the per-block
-    Python work is only the two slices + namedtuple per code, and the repair
-    loop runs solely for blocks whose verified error still exceeds ``tau``.
+    Code construction is vectorized (membership masks and the ascending-index
+    extraction are whole-batch numpy passes); the per-block Python work is
+    only the namedtuple per code, and the repair rounds touch only blocks
+    whose verified error still exceeds ``tau``.
     """
     from repro.core import exec as exec_mod
 
@@ -233,57 +237,81 @@ def gae_encode_blocks(x: np.ndarray, x_r: np.ndarray, basis: np.ndarray,
     else:
         select = exec_mod.cache().get("gae_select", gae_select,
                                       static_argnames=("use_kernel",))
-        sel = jax.device_get(select(jnp.asarray(x - x_r), jnp.asarray(u),
-                                    tau, bin_size))
-    out = x_r + np.asarray(sel.corrected)
+        sel = select(jnp.asarray(x - x_r), jnp.asarray(u), tau, bin_size)
+    # only the codes leave the device: the encoder never trusts the
+    # selection's own ``corrected``/``err``
+    m_sel, order_sel, q_sorted = jax.device_get(
+        (sel.m, sel.order, sel.q_sorted))
 
     # batch extraction in ascending index order: scatter the kept-coefficient
     # membership and quantized values from sorted-magnitude space back to
     # index space, then one np.nonzero walks every block's set in index order.
-    ms = np.asarray(sel.m, np.int64)
-    order64 = np.asarray(sel.order, np.int64)
+    ms = np.asarray(m_sel, np.int64)
+    order64 = np.asarray(order_sel, np.int64)
     keep = np.arange(d)[None, :] < ms[:, None]            # sorted-mag space
     mask = np.zeros((n, d), bool)
     np.put_along_axis(mask, order64, keep, axis=1)
     q_idx_space = np.zeros((n, d), np.int32)
     np.put_along_axis(q_idx_space, order64,
-                      np.asarray(sel.q_sorted, np.int32), axis=1)
+                      np.asarray(q_sorted, np.int32), axis=1)
     rows, cols = np.nonzero(mask)                          # row-major: ascending
     idx_all = cols.astype(np.int32)
     q_all = q_idx_space[rows, cols].astype(np.int64)
     bounds = np.zeros(n + 1, np.int64)
-    np.cumsum(mask.sum(axis=1), out=bounds[1:])
-    errs = np.linalg.norm(x - out, axis=1)
+    np.cumsum(ms, out=bounds[1:])
+    idx_list = [idx_all[bounds[i]:bounds[i + 1]] for i in range(n)]
+    q_list = [q_all[bounds[i]:bounds[i + 1]] for i in range(n)]
+    bin_exps = np.zeros(n, np.int64)
 
-    codes: list[GAEBlockCode] = []
-    ms_list = ms.tolist()
-    bounds_list = bounds.tolist()
-    for i in range(n):
-        m = ms_list[i]
-        bin_exp = 0
-        b = bin_size
-        idx = idx_all[bounds_list[i]:bounds_list[i + 1]]
-        q = q_all[bounds_list[i]:bounds_list[i + 1]]
-        err = errs[i]
-        # verify & repair (numerical safety + coarse-bin fallback)
-        while err > tau and bin_exp < max_refine:
-            if m < d:
-                m = min(d, m + max(1, d // 32))
+    # verify against the decoder's arithmetic & repair (numerical safety +
+    # coarse-bin fallback), in rounds over the blocks still above tau
+    out = _apply_codes(x_r, u, ms, idx_all, q_all, bin_exps, bin_size)
+    bad = np.flatnonzero(np.linalg.norm(x - out, axis=1) > tau)
+    while bad.size:
+        stuck = bad[bin_exps[bad] >= max_refine]
+        if stuck.size:
+            i = int(stuck[0])
+            raise GuaranteeUnsatisfiable(
+                block=i, err=float(np.linalg.norm(x[i] - out[i])), tau=tau,
+                max_refine=max_refine)
+        c = (x[bad] - x_r[bad]) @ u
+        order = np.argsort(-np.square(c), axis=1)
+        for j, i in enumerate(bad.tolist()):
+            if ms[i] < d:
+                ms[i] = min(d, ms[i] + max(1, d // 32))
             else:
-                bin_exp += 1
-                b = bin_size / (2 ** bin_exp)
-            c = u.T @ (x[i] - x_r[i])
-            order = np.argsort(-np.square(c))
-            idx = np.sort(order[:m]).astype(np.int32)
-            q = np.round(c[idx] / b).astype(np.int64)
-            rec = x_r[i] + u[:, idx] @ (q.astype(np.float32) * b)
-            err = float(np.linalg.norm(x[i] - rec))
-            out[i] = rec
-        if err > tau:
-            raise GuaranteeUnsatisfiable(block=i, err=err, tau=tau,
-                                         max_refine=max_refine)
-        codes.append(GAEBlockCode(m, idx, q, bin_exp))
+                bin_exps[i] += 1
+            idx = np.sort(order[j, :ms[i]]).astype(np.int32)
+            idx_list[i] = idx
+            q_list[i] = np.round(
+                c[j, idx] / (bin_size / 2 ** bin_exps[i])).astype(np.int64)
+        out = _apply_codes(x_r, u, ms, np.concatenate(idx_list),
+                           np.concatenate(q_list), bin_exps, bin_size)
+        bad = np.flatnonzero(np.linalg.norm(x - out, axis=1) > tau)
+
+    ms_list = ms.tolist()
+    be_list = bin_exps.tolist()
+    codes = [GAEBlockCode(ms_list[i], idx_list[i], q_list[i], be_list[i])
+             for i in range(n)]
     return out, codes
+
+
+def _apply_codes(x_r: np.ndarray, u: np.ndarray, ms: np.ndarray,
+                 cols: np.ndarray, qs: np.ndarray, bin_exps: np.ndarray,
+                 bin_size: float) -> np.ndarray:
+    """x^R + U c for flat codes: block i owns the next ``ms[i]`` entries of
+    ``cols``/``qs``.  The one place the GAE correction is computed, so the
+    encoder verifies exactly the floats the decoder will produce."""
+    out = np.asarray(x_r, np.float32).copy()
+    if not ms.sum():
+        return out
+    rows = np.repeat(np.arange(len(ms)), ms)
+    b_vals = (bin_size / np.exp2(bin_exps.astype(np.float64)))[rows]
+    coeffs = np.zeros(out.shape, np.float32)
+    coeffs[rows, np.asarray(cols, np.int64)] = \
+        np.asarray(qs).astype(np.float32) * b_vals.astype(np.float32)
+    out += coeffs @ u.T
+    return out
 
 
 def gae_decode_blocks(x_r: np.ndarray, basis: np.ndarray, codes: list[GAEBlockCode],
@@ -296,18 +324,10 @@ def gae_decode_blocks(x_r: np.ndarray, basis: np.ndarray, codes: list[GAEBlockCo
     instead of a per-block Python loop.
     """
     u = np.asarray(basis, np.float32)
-    out = np.asarray(x_r, np.float32).copy()
     if not codes:
-        return out
+        return np.asarray(x_r, np.float32).copy()
     ms = np.fromiter((c.m for c in codes), np.int64, len(codes))
-    if not ms.sum():
-        return out
-    rows = np.repeat(np.arange(len(codes)), ms)
-    cols = np.concatenate([c.indices for c in codes]).astype(np.int64)
-    qs = np.concatenate([c.qcoeffs for c in codes]).astype(np.float32)
     binexps = np.fromiter((c.bin_exp for c in codes), np.int64, len(codes))
-    b_vals = (bin_size / np.exp2(binexps.astype(np.float64)))[rows]
-    coeffs = np.zeros(out.shape, np.float32)
-    coeffs[rows, cols] = qs * b_vals.astype(np.float32)
-    out += coeffs @ u.T
-    return out
+    cols = np.concatenate([c.indices for c in codes])
+    qs = np.concatenate([c.qcoeffs for c in codes])
+    return _apply_codes(x_r, u, ms, cols, qs, binexps, bin_size)

@@ -580,13 +580,13 @@ class HierarchicalCompressor:
         latents with no GAE correction (and no guarantee), every other stripe
         is digest-verified and still satisfies the per-block bound.
 
-        ``mesh`` (anything ``parallel.mesh_exec.resolve_mesh`` accepts) runs
-        the fused dequantize+decode back-end sharded over the hyper-block
-        axis.  The sharded back-end pads the batch to an even shard split, so
-        its floats can differ from the single-device decode in the last ulp —
-        well inside the ``tau * (1 + 1e-5)`` slack every guarantee check in
-        this repo carries.  Entropy decode and GAE correction are unchanged
-        (host-side, chunk-parallel).
+        The AE back-end and the GAE correction run stripe by stripe, on the
+        shapes ``compress`` ran (``_ae_decode``), so the decoded floats are
+        the ones the encoder verified against tau.  ``mesh`` (anything
+        ``parallel.mesh_exec.resolve_mesh`` accepts) runs the back-end as
+        ``compress`` with the same mesh does: aligned groups of stripes, one
+        per shard.  Entropy decode and GAE correction are host-side and
+        chunk-parallel.
         """
         cfg = self.cfg
         n, k, d = archive.n_hyperblocks, cfg.k, cfg.block_elems
@@ -606,10 +606,9 @@ class HierarchicalCompressor:
         q_lh = np.zeros((n, cfg.hb_latent), np.int64)
         q_lbs = [np.zeros((n * k, cfg.bae_latent), np.int64)
                  for _ in self.bae_params]
-        gae_codes: dict[int, gae.GAEBlockCode] = {}   # global gae-block index
+        spans: list[tuple[int, int]] = []     # the stripes compress coded
+        gae_stripes: list[tuple[int, int, list[gae.GAEBlockCode]]] = []
         verbatim_spans: list[tuple[int, int, np.ndarray]] = []
-        d_gae = cfg.gae_block_elems or d
-        gae_per_hb = (k * d) // d_gae if archive.gae_dim else 0
 
         # Chunks are independently decodable (docs/ARCHIVE_FORMAT.md), so the
         # entropy fan-out runs on the shared pool; per-chunk errors are
@@ -632,6 +631,7 @@ class HierarchicalCompressor:
                 start = covered
                 n_hb = min(archive.chunk_hyperblocks, n - start)
                 covered += n_hb
+                spans.append((start, n_hb))
                 err = archive.chunk_errors.get(ci, "chunk unreadable")
                 if strict:
                     raise MalformedStream(f"chunk {ci} damaged: {err}")
@@ -644,6 +644,7 @@ class HierarchicalCompressor:
                     f"chunk {ci} starts at hyper-block {chunk.hb_start}, "
                     f"expected {covered}")
             covered += chunk.n_hyperblocks
+            spans.append((chunk.hb_start, chunk.n_hyperblocks))
             if isinstance(result, ArchiveError):
                 if strict:
                     raise result
@@ -664,44 +665,63 @@ class HierarchicalCompressor:
             q_lh[s:e] = c_lh
             for stage_i, c_lb in enumerate(c_lbs):
                 q_lbs[stage_i][s * k:e * k] = c_lb
-            for j, code in enumerate(c_codes):
-                gae_codes[s * gae_per_hb + j] = code
+            if c_codes:
+                gae_stripes.append((s, e, c_codes))
         if covered != n:
             raise MalformedStream(
                 f"chunks cover {covered} hyper-blocks, archive declares {n}")
 
-        # fused dequantize+decode back-end — the same cached program that
-        # produced the reconstruction the GAE encoder verified against
-        # (shard_map-wrapped over the hyper-block axis when a mesh is active).
         resolved_mesh = None
         if mesh is not None:
             from repro.parallel import mesh_exec
             resolved_mesh = mesh_exec.resolve_mesh(mesh)
         with exec_mod.stage("ae_decode", archive.n_values):
-            if resolved_mesh is not None:
-                recon = exec_mod.run_decompress_stage_sharded(
-                    self.hbae_params, self.bae_params, q_lh, q_lbs,
-                    cfg.hb_bin, cfg.bae_bin, resolved_mesh)
-            else:
-                recon = exec_mod.run_decompress_stage(
-                    self.hbae_params, self.bae_params, q_lh, q_lbs,
-                    cfg.hb_bin, cfg.bae_bin)
+            recon = self._ae_decode(q_lh, q_lbs, spans, resolved_mesh)
 
-        if archive.gae_dim and gae_codes:
+        # GAE correction stripe by stripe: the encoder verified each stripe
+        # with this same arithmetic on this same block batch
+        def correct(item) -> None:
+            s, e, codes = item
+            r_gae = self._gae_view(recon[s:e])          # a view into recon
+            r_gae[:] = gae.gae_decode_blocks(r_gae, self.basis, codes,
+                                             cfg.gae_bin)
+
+        if gae_stripes:
             with exec_mod.stage("gae_decode", archive.n_values):
-                r_gae = self._gae_view(recon)
-                keys = sorted(gae_codes)
-                idxs = np.fromiter(keys, np.int64, len(keys))
-                sub = gae.gae_decode_blocks(r_gae[idxs], self.basis,
-                                            [gae_codes[i] for i in keys],
-                                            cfg.gae_bin)
-                r_gae[idxs] = sub
-                recon = self._gae_unview(r_gae, recon.shape)
+                exec_mod.map_parallel(correct, gae_stripes)
         for s, e, data in verbatim_spans:
             recon[s:e] = data
         if strict:
             return recon
         return recon, report
+
+    def _ae_decode(self, q_lh: np.ndarray, q_lbs: list[np.ndarray],
+                   spans: list[tuple[int, int]], mesh) -> np.ndarray:
+        """Fused dequantize+decode back-end over the archive's own stripe
+        tiling.  Each stripe runs the program ``compress`` ran on it, at the
+        same shape (with a mesh: one stripe per shard in the aligned groups
+        ``compress`` forms, the ragged tail per stripe), so the
+        reconstruction is bit-identical to the one the GAE encoder verified.
+        A program compiled for another batch shape may round differently —
+        on a TPU, whose default-precision matmuls take bf16 passes, enough to
+        push blocks past tau."""
+        cfg = self.cfg
+        k = cfg.k
+        runs = [(s, s + w, None) for s, w in spans]
+        if mesh is not None:
+            from repro.parallel import mesh_exec
+            groups, tail = mesh_exec.plan_shard_groups(
+                spans, mesh_exec.mesh_shards(mesh))
+            runs = ([(*mesh_exec.group_slice(g), mesh) for g in groups]
+                    + [(s, s + w, None) for s, w in tail])
+        handles = [exec_mod.run_decompress_stage_async(
+            self.hbae_params, self.bae_params, q_lh[a:b],
+            [q[a * k:b * k] for q in q_lbs], cfg.hb_bin, cfg.bae_bin, mesh=m)
+            for a, b, m in runs]
+        recon = np.empty((q_lh.shape[0], k, cfg.block_elems), np.float32)
+        for (a, b, _), part in zip(runs, jax.device_get(handles)):
+            recon[a:b] = part
+        return recon
 
     # -- persistence ---------------------------------------------------------
     # Manifest + npz layout (no pickle anywhere on the read path): a single

@@ -31,10 +31,17 @@ def _fourier_field(rng: np.random.Generator, t: int, h: int, w: int,
     advection, as in real ignition fronts): phase(t) = omega*(t + a*T*
     sin(2*pi*t/T + phi)).  Inter-block temporal relationships then VARY by
     position in the sequence — the structure content-based attention can
-    exploit but a fixed linear cross-block mix cannot."""
+    exploit but a fixed linear cross-block mix cannot.
+
+    The modes are drawn first, then summed over slabs of time steps on the
+    shared codec pool; every value takes the same operations in the same
+    order as a serial sum, so the field does not depend on the worker count."""
+    from repro.core import exec as exec_mod
+
     ys, xs = np.meshgrid(np.arange(h), np.arange(w), indexing="ij")
     field = np.zeros((t, h, w), np.float32)
     ts = np.arange(t, dtype=np.float64)[:, None, None]
+    modes = []
     for _ in range(n_modes):
         kx = rng.integers(1, max(2, w // 8))
         ky = rng.integers(1, max(2, h // 8))
@@ -44,8 +51,19 @@ def _fourier_field(rng: np.random.Generator, t: int, h: int, w: int,
         aw = warp * rng.uniform(0, 1)
         tw = ts + aw * t / (2 * np.pi) * np.sin(2 * np.pi * ts / t +
                                                 rng.uniform(0, 2 * np.pi))
-        arg = (2 * np.pi * (kx * xs / w + ky * ys / h))[None] + omega * tw + phase
-        field += (amp * np.cos(arg)).astype(np.float32)
+        modes.append((amp, (2 * np.pi * (kx * xs / w + ky * ys / h))[None],
+                      omega * tw, phase))
+
+    slab = max(1, (1 << 20) // (h * w))          # ~1M values per task
+
+    def add_modes(t0: int) -> None:
+        sl = slice(t0, t0 + slab)
+        for amp, spatial, advect, phase in modes:
+            arg = spatial + advect[sl]
+            arg += phase
+            field[sl] += (amp * np.cos(arg)).astype(np.float32)
+
+    exec_mod.map_parallel(add_modes, range(0, t, slab))
     return field
 
 

@@ -1,5 +1,5 @@
 """Jit'd public wrapper around the fused quantize kernel: any input shape,
-padded 2-D tiling underneath, interpret off-TPU."""
+padded to whole 2-D tiles underneath, interpret off-TPU."""
 from __future__ import annotations
 
 import functools
@@ -21,7 +21,9 @@ def quantize_fused(x: Array, bin_size: float,
     shape = x.shape
     flat = x.reshape(-1)
     c = min(512, flat.size)
-    pad = -flat.size % c
+    rows = -(-flat.size // c)
+    tr = min(256, rows)                       # the kernel's row tile
+    pad = -(-rows // tr) * tr * c - flat.size
     if pad:
         flat = jnp.pad(flat, (0, pad))
     x2 = flat.reshape(-1, c)

@@ -100,6 +100,7 @@ def main(argv=None) -> int:
             resolve_mesh(opts.mesh)     # fail fast on impossible meshes
     except ConfigError as e:
         ap.error(str(e))
+    exec_mod.use_compile_cache()
 
     cfg, hyperblocks = synthetic.make_dataset(args.dataset, quick=args.quick,
                                               seed=args.seed,

@@ -174,7 +174,7 @@ def main() -> int:
     d_gae = comp.cfg.gae_block_elems or comp.cfg.block_elems
     errs = np.linalg.norm((hb - dec_sharded).reshape(-1, d_gae), axis=1)
     check("sharded_decompress",
-          bool(np.allclose(dec_sharded, dec_single, rtol=1e-5, atol=1e-6))
+          bool(np.array_equal(dec_sharded, dec_single))
           and float(errs.max()) <= TAU * (1 + 1e-5),
           f"max block l2 {float(errs.max()):.4f} <= tau={TAU}, "
           f"max |recon diff| = "

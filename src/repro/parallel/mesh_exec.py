@@ -36,7 +36,6 @@ from __future__ import annotations
 from typing import Optional, Sequence
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -174,9 +173,9 @@ def fit_pca_basis_sharded(residuals: np.ndarray, mesh: Mesh) -> np.ndarray:
     def local_fit(rr):
         return gae.fit_pca_basis(rr, axis_name=MESH_AXIS)
 
-    from jax.experimental.shard_map import shard_map
-    fn = shard_map(local_fit, mesh=mesh, in_specs=(P(MESH_AXIS),),
-                   out_specs=P(), check_rep=False)
+    fn = jax.shard_map(local_fit, mesh=mesh, in_specs=(P(MESH_AXIS),),
+                       out_specs=P(), check_vma=False)
     fit = exec_mod.cache().get("fit_pca_basis_sharded", fn, mesh=mesh)
     with exec_mod.stage("fit_basis_sharded", r.size):
-        return np.asarray(jax.device_get(fit(jnp.asarray(r))))
+        return np.asarray(jax.device_get(
+            fit(exec_mod.put_sharded(r, mesh))))

@@ -123,3 +123,38 @@ def test_gae_encode_blocks_coarse_bin_fallback():
     errs = np.linalg.norm(x - out, axis=1)
     assert np.all(errs <= tau + 1e-5)
     assert any(c.bin_exp > 0 for c in codes)
+
+
+@pytest.mark.parametrize("operand_dtype", [jnp.float32, jnp.bfloat16])
+def test_gae_encode_device_branch_holds_tau_on_decode(monkeypatch,
+                                                      operand_dtype):
+    """Off the CPU backend the encoder selects with the jitted ``gae_select``,
+    whose matmuls a TPU may run with bf16 operands at default precision.
+    Steer the encoder onto that branch (bf16 case: with the selection's
+    operands rounded as such a pass rounds them) and check every block the
+    decoder rebuilds from the emitted codes against tau, with no slack."""
+    from repro.core import exec as exec_mod
+    rng = np.random.default_rng(5)
+    n, d, tau, bin_size = 2048, 256, 0.5, 0.01
+    basis = np.linalg.qr(rng.standard_normal((d, d)))[0].astype(np.float32)
+    x = rng.standard_normal((n, d)).astype(np.float32)
+    x_r = x + 0.05 * rng.standard_normal((n, d)).astype(np.float32)
+
+    exact_select = gae.gae_select
+
+    def select_at_operand_precision(residuals, u, tau, bin_size,
+                                    use_kernel=False):
+        def rd(a):
+            return a.astype(operand_dtype).astype(jnp.float32)
+        return exact_select(rd(residuals), rd(u), tau, bin_size)
+
+    monkeypatch.setattr(exec_mod, "_CACHE", exec_mod.JitCache())
+    monkeypatch.setattr(gae, "gae_select", select_at_operand_precision)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    out, codes = gae.gae_encode_blocks(x, x_r, basis, tau, bin_size)
+    assert exec_mod.retrace_counts() == {"gae_select": 1}   # device branch
+    dec = gae.gae_decode_blocks(x_r, basis, codes, bin_size)
+    np.testing.assert_array_equal(dec, out)
+    errs = np.linalg.norm(x - dec, axis=1)
+    assert errs.max() <= tau, (int((errs > tau).sum()), errs.max())
+    assert all(c.m > 0 for c in codes)

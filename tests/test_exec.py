@@ -2,6 +2,8 @@
 fused stage programs, chunk-parallel codecs, and the guarantee/accounting
 bugfix regressions (GuaranteeUnsatisfiable, model_bytes dtypes, cached
 compressed_bytes, strict/tolerant decode parity)."""
+import pathlib
+
 import numpy as np
 import pytest
 
@@ -81,6 +83,23 @@ def test_roundtrip_retrace_stable_after_warmup(comp_hb):
         a = comp.compress(hb, tau=0.5)
         comp.decompress(a)
     assert exec_mod.total_retraces() == before, exec_mod.retrace_counts()
+
+
+def test_decompress_decodes_at_the_stripe_shapes_compress_ran(comp_hb,
+                                                              monkeypatch):
+    # the GAE encoder verified tau against compress's per-stripe decode; a
+    # program compiled for another batch shape may round differently on an
+    # accelerator, so decompress must not compile one
+    from repro.core.options import CompressOptions
+    comp, hb = comp_hb
+    monkeypatch.setattr(exec_mod, "_CACHE", exec_mod.JitCache())
+    archive = comp.compress(hb, options=CompressOptions(
+        tau=0.5, chunk_hyperblocks=10))              # stripes of 10, 10, 4
+    assert exec_mod.retrace_counts()["decode_backend"] == 2
+    recon = comp.decompress(archive)
+    assert exec_mod.retrace_counts()["decode_backend"] == 2
+    errs = np.linalg.norm((hb - recon).reshape(-1, 80), axis=1)
+    assert errs.max() <= 0.5
 
 
 def test_stage_stats_accumulate():
@@ -322,3 +341,27 @@ def test_index_set_codec_roundtrip_with_empty_sets():
     assert len(back) == len(sets)
     for a, b in zip(sets, back):
         np.testing.assert_array_equal(a, b)
+
+
+# ---------------------------------------------------------------------------
+# persistent compilation cache placement
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("env_set", [True, False])
+def test_use_compile_cache_places_cache(monkeypatch, tmp_path, env_set):
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        if env_set:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+            assert exec_mod.use_compile_cache() == str(tmp_path)
+            # JAX reads the variable itself: no other directory is set
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            path = exec_mod.use_compile_cache()
+            root = pathlib.Path(__file__).resolve().parents[1]
+            assert path == str(root / ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == path
+            assert exec_mod.use_compile_cache() == path   # fixed, not fresh
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

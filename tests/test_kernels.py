@@ -148,7 +148,8 @@ def test_gae_project_matches_gae_select_path():
 # fused quantize
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("shape", [(1000,), (64, 33, 7), (2, 3), (4096,)])
+@pytest.mark.parametrize("shape", [(1000,), (64, 33, 7), (2, 3), (4096,),
+                                   (1920, 256)])   # rows not a tile multiple
 @pytest.mark.parametrize("bin_size", [0.005, 0.1, 0.5])
 def test_quantize_sweep(shape, bin_size):
     x = jax.random.normal(jax.random.fold_in(KEY, shape[0] + int(bin_size * 1e3)),

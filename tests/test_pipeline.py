@@ -93,3 +93,12 @@ def test_normalizer_roundtrip():
     nz = blocks_mod.Normalizer.fit(data, mode="zscore")
     np.testing.assert_allclose(nz.inverse(nz.forward(data)), data, rtol=1e-4,
                                atol=1e-3)
+
+
+def test_fourier_field_independent_of_worker_count(monkeypatch):
+    # 20 time steps of 256x512 sum in three slabs on the codec pool
+    fields = []
+    for workers in ("1", "4"):
+        monkeypatch.setenv("REPRO_CODEC_WORKERS", workers)
+        fields.append(synthetic.e3sm_like(t=20, h=256, w=512, seed=3))
+    np.testing.assert_array_equal(fields[0], fields[1])
