@@ -1,0 +1,6 @@
+"""Host-clock ms in the ``gae_decode`` stage per MB of float32 values."""
+from bench.metrics import stage_ms_per_MB
+
+
+def read(ctx):
+    return stage_ms_per_MB(ctx, "gae_decode")

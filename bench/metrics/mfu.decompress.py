@@ -1,0 +1,6 @@
+"""Model FLOPs of decompress per second over the chips' bf16 peak."""
+from bench.metrics import mfu_pct
+
+
+def read(ctx):
+    return mfu_pct(ctx)
