@@ -19,8 +19,9 @@ with ``CompressOptions(mesh=4)``, and decodes each; it fails unless the two
 archives are byte-identical, the sharded decode is bit-identical to the
 single-device decode, and tau holds on every block.
 
-The times printed are wall seconds of bring-up runs, not benchmark numbers.
-The last line of stdout is ``{"ok": true, "device": {...}}`` and appears
+Each phase ends with ``exec.stats_summary()``: the program's own stage
+spans and counters, from a bring-up run, not speed measurements.  The last
+line of stdout is ``{"ok": true, "device": {...}}`` and appears
 only if every check passed; without a TPU the script exits nonzero before
 doing any work.
 """
@@ -31,7 +32,6 @@ import json
 import os
 import shutil
 import sys
-import time
 from pathlib import Path
 
 ROOT = Path(__file__).resolve().parent
@@ -59,13 +59,6 @@ def _check(ok: bool, what: str) -> None:
     if not ok:
         raise SmokeFailure(what)
     _say(f"check ok: {what}")
-
-
-def _timed(label: str, fn, *args, **kwargs):
-    t0 = time.perf_counter()
-    out = fn(*args, **kwargs)
-    _say(f"time {label}: {time.perf_counter() - t0:.3f} s (wall)")
-    return out
 
 
 def _block_errs(hyperblocks, recon, d_gae: int):
@@ -104,9 +97,8 @@ def _fit(dataset: str, quick: bool, seed: int, epochs_scale: float):
     from repro.core.pipeline import HierarchicalCompressor
     from repro.data import synthetic
 
-    cfg, hb = _timed("data generation (host)", synthetic.make_dataset,
-                     dataset, quick=quick, seed=seed,
-                     epochs_scale=epochs_scale)
+    cfg, hb = synthetic.make_dataset(dataset, quick=quick, seed=seed,
+                                     epochs_scale=epochs_scale)
     d_gae = cfg.gae_block_elems or cfg.block_elems
     _say(f"dataset {dataset}: shape {hb.shape} ({hb.size} values, "
          f"{hb.size // d_gae} GAE blocks of {d_gae})")
@@ -115,12 +107,9 @@ def _fit(dataset: str, quick: bool, seed: int, epochs_scale: float):
          f"{cfg.bae_latent} epochs_hbae={cfg.epochs_hbae} "
          f"epochs_bae={cfg.epochs_bae}")
 
-    def fit():
-        comp = HierarchicalCompressor(cfg).fit(hb, seed=seed)
-        jax.block_until_ready((comp.hbae_params, comp.bae_params))
-        return comp
-
-    return _timed("fit", fit), hb, d_gae
+    comp = HierarchicalCompressor(cfg).fit(hb, seed=seed)
+    jax.block_until_ready((comp.hbae_params, comp.bae_params))
+    return comp, hb, d_gae
 
 
 def run_single_chip(dataset: str = DATASET, *, quick: bool = False,
@@ -139,16 +128,16 @@ def run_single_chip(dataset: str = DATASET, *, quick: bool = False,
     from repro.runtime import archive_io
     from repro.stream import stream_compress
 
+    exec_mod.reset_stage_stats()          # the summary covers this phase
     comp, hb, d_gae = _fit(dataset, quick, seed, epochs_scale)
     opts = CompressOptions(tau=tau, chunk_hyperblocks=chunk_hyperblocks)
-    archive = _timed("compress", comp.compress, hb, options=opts)
+    archive = comp.compress(hb, options=opts)
     _check(not archive.verbatim_chunks(), "batch compress: no stripe "
            "quarantined")
 
     out_dir.mkdir(parents=True, exist_ok=True)
     path = out_dir / f"{dataset}.rba"
-    result = _timed("stream compress", stream_compress, comp, hb,
-                    options=opts, out_path=str(path))
+    result = stream_compress(comp, hb, options=opts, out_path=str(path))
     s = result.stats
     _say(f"stream: {s.n_items} items, retries {dict(s.retries)}, deadline "
          f"hits {dict(s.deadline_hits)}, failovers {dict(s.failovers)}, "
@@ -162,7 +151,7 @@ def run_single_chip(dataset: str = DATASET, *, quick: bool = False,
            f"streamed .rba is byte-identical to the batch archive "
            f"({len(batch_bytes)} bytes)")
 
-    recon = _timed("decompress", comp.decompress, archive)
+    recon = comp.decompress(archive)
     errs = _block_errs(hb, recon, d_gae)
     ratio = archive.compression_ratio()
     err = nrmse(hb, recon)
@@ -171,13 +160,13 @@ def run_single_chip(dataset: str = DATASET, *, quick: bool = False,
          f"blocks with m > 0: {share!r}")
     _check_tau(errs, tau, "in-memory decode")
 
-    disk = _timed("read archive", archive_io.read_archive, str(path))
-    recon2 = _timed("decompress from disk", comp.decompress, disk)
+    disk = archive_io.read_archive(str(path))
+    recon2 = comp.decompress(disk)
     _check_tau(_block_errs(hb, recon2, d_gae), tau, "disk round-trip decode")
     _check(np.array_equal(recon2, recon),
            "disk round trip decodes bit-exactly")
     path.unlink()
-    _say(f"retrace counts: {exec_mod.retrace_counts()}")
+    _say(exec_mod.stats_summary())
     return {"shape": hb.shape, "ratio": ratio, "nrmse": err,
             "max_l2": float(errs.max()), "coded_share": share,
             "archive_bytes": len(batch_bytes)}
@@ -195,22 +184,20 @@ def run_mesh(n_shards: int, dataset: str = DATASET, *, quick: bool = False,
     from repro.core.options import CompressOptions
     from repro.runtime import archive_io
 
+    exec_mod.reset_stage_stats()          # the summary covers this phase
     comp, hb, d_gae = _fit(dataset, quick, seed, epochs_scale)
     opts = CompressOptions(tau=tau, chunk_hyperblocks=chunk_hyperblocks)
-    single = _timed("compress (1 device)", comp.compress, hb, options=opts)
-    sharded = _timed(f"compress (mesh={n_shards})", comp.compress, hb,
-                     options=opts.replace(mesh=n_shards))
-    _say(f"counters: {exec_mod.counters()}")
+    single = comp.compress(hb, options=opts)
+    sharded = comp.compress(hb, options=opts.replace(mesh=n_shards))
     same = (archive_io.serialize_archive(sharded)
             == archive_io.serialize_archive(single))
     _say(f"sharded archive byte-identical to single-device: {same}")
-    recon1 = _timed("decompress (1 device)", comp.decompress, single)
-    recon_n = _timed(f"decompress (mesh={n_shards})", comp.decompress,
-                     sharded, mesh=n_shards)
+    recon1 = comp.decompress(single)
+    recon_n = comp.decompress(sharded, mesh=n_shards)
     diff = float(np.abs(recon_n - recon1).max())
     _say(f"mesh={n_shards} decode bit-identical to single-device: "
          f"{bool(np.array_equal(recon_n, recon1))}, max |diff| {diff!r}")
-    _say(f"retrace counts: {exec_mod.retrace_counts()}")
+    _say(exec_mod.stats_summary())
 
     # every comparison is printed before the first failed check stops the
     # phase

@@ -30,16 +30,21 @@ jax's compilation cache and retraces + recompiles the model on every
    docs/ARCHIVE_FORMAT.md), and the Huffman/index-set work is numpy/zlib
    dominated (GIL-releasing), so a thread pool scales the host-side loops.
 
-Stage-level timing/throughput counters (``stage`` / ``stage_stats``) wrap
-each hot-path phase; ``launch/compress.py`` prints them and
-``benchmarks/bench_pipeline_throughput.py`` records them into
-``BENCH_pipeline.json``.
+4. **Spans and counters** (``stage`` / ``stage_stats`` / ``counter_*``):
+   each hot-path stage records its calls, wall and on-CPU seconds and the
+   values it processed, and opens a ``repro/<stage>`` profiler annotation;
+   ``to_device`` / ``to_host`` count the bytes every upload and fetch moves.
+   ``stats_summary()`` prints them (``launch/compress.py`` does after a
+   run).
 """
 from __future__ import annotations
 
 import contextlib
+import ctypes
 import dataclasses
 import os
+import re
+import sys
 import threading
 import time
 from concurrent.futures import Future, ThreadPoolExecutor
@@ -104,6 +109,8 @@ class JitCache:
                 def counted(*args, __fn=fn, __name=name, **kwargs):
                     self.count_retrace(__name)
                     return __fn(*args, **kwargs)
+                # jit names the program after the wrapper: ``jit_<name>``
+                counted.__name__ = counted.__qualname__ = program_name(name)
                 cached = jax.jit(counted, static_argnums=static_argnums,
                                  static_argnames=static_argnames)
                 self._fns[key] = cached
@@ -120,6 +127,12 @@ class JitCache:
     def total_retraces(self) -> int:
         with self._lock:
             return sum(self._retraces.values())
+
+
+def program_name(name: str) -> str:
+    """A cache key's name as a program name: ``decode_backend@hb4`` ->
+    ``decode_backend_hb4`` (the HLO module is ``jit_<program_name>``)."""
+    return re.sub(r"[^A-Za-z0-9_]", "_", name)
 
 
 _CACHE = JitCache()
@@ -172,33 +185,54 @@ class StageStat:
     calls: int = 0
     seconds: float = 0.0
     values: int = 0
+    #: on-CPU seconds of the threads inside the stage; on a host-only stage,
+    #: ``seconds - cpu_seconds`` is time spent waiting for the GIL, a lock or
+    #: a core
+    cpu_seconds: float = 0.0
 
     def values_per_s(self) -> float:
         return self.values / self.seconds if self.seconds > 0 else 0.0
 
+    def oncpu_share(self) -> float:
+        return self.cpu_seconds / self.seconds if self.seconds > 0 else 0.0
+
 
 _STAGES: dict[str, StageStat] = {}
 _STAGE_LOCK = threading.Lock()
+#: ``repro/<stage>`` annotation names, built once per stage name
+_TRACE_NAMES: dict[str, str] = {}
 
 
 @contextlib.contextmanager
 def stage(name: str, n_values: int = 0):
-    """Time one hot-path stage; accumulates wall time + processed values.
+    """Time one hot-path stage; accumulates wall and on-CPU time and the
+    values it processed.
+
+    The stage is also a ``repro/<name>`` profiler annotation, on the clock
+    the device planes share, so a trace shows the program's spans beside
+    the device's operations; with no profiler active it costs one check.
+    Stages nest: an inner stage's time is also its outer stage's.
 
     Thread-safe: the codec worker pool and the streaming scheduler both enter
     stages concurrently, so every read-modify-write of the accumulator happens
     under ``_STAGE_LOCK`` (the ``StageStat`` instances themselves are only
     ever mutated while the lock is held; ``stage_stats`` hands out copies).
     """
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        record_stage(name, time.perf_counter() - t0, n_values)
+    trace_name = _TRACE_NAMES.get(name)
+    if trace_name is None:
+        trace_name = _TRACE_NAMES.setdefault(name, "repro/" + name)
+    with jax.profiler.TraceAnnotation(trace_name):
+        c0 = time.thread_time()
+        t0 = time.perf_counter()
+        try:
+            yield
+        finally:
+            record_stage(name, time.perf_counter() - t0, n_values,
+                         cpu_seconds=time.thread_time() - c0)
 
 
 def record_stage(name: str, seconds: float, n_values: int = 0,
-                 calls: int = 1) -> None:
+                 calls: int = 1, cpu_seconds: float = 0.0) -> None:
     """Accumulate a pre-measured duration into a stage counter (the streaming
     scheduler measures busy time inside worker threads and folds it in here).
     Thread-safe."""
@@ -207,6 +241,7 @@ def record_stage(name: str, seconds: float, n_values: int = 0,
         st.calls += int(calls)
         st.seconds += float(seconds)
         st.values += int(n_values)
+        st.cpu_seconds += float(cpu_seconds)
 
 
 def stage_stats() -> dict[str, StageStat]:
@@ -246,12 +281,38 @@ def counters() -> dict[str, float]:
         return dict(_COUNTERS)
 
 
+# -- host<->device transfers --------------------------------------------------
+# Every upload and fetch of the compress and decompress paths goes through
+# these two, which count the bytes moved under ``xfer.h2d_bytes`` and
+# ``xfer.d2h_bytes``.
+
+def to_device(a, sharding=None) -> Array:
+    """Upload one host array (with ``sharding``: split over its devices);
+    an array already on a device is not counted."""
+    moved = not isinstance(a, jax.Array)
+    out = (jnp.asarray(a) if sharding is None
+           else jax.device_put(np.asarray(a) if moved else a, sharding))
+    if moved:
+        counter_add("xfer.h2d_bytes", out.nbytes)
+    return out
+
+
+def to_host(tree):
+    """``jax.device_get(tree)``; counts the bytes of its device arrays."""
+    counter_add("xfer.d2h_bytes", sum(
+        x.nbytes for x in jax.tree.leaves(tree) if isinstance(x, jax.Array)))
+    return jax.device_get(tree)
+
+
 def stats_summary() -> str:
     """Human-readable per-stage throughput + counter + retrace report."""
     lines = []
     for name, st in sorted(stage_stats().items()):
+        # durations folded in by ``record_stage`` may carry no CPU time
+        oncpu = (f", {100 * st.oncpu_share():.0f}% on CPU"
+                 if st.cpu_seconds else "")
         lines.append(f"{name}: {st.calls} calls, {st.seconds:.3f}s, "
-                     f"{st.values_per_s() / 1e6:.2f} Mvalues/s")
+                     f"{st.values_per_s() / 1e6:.2f} Mvalues/s{oncpu}")
     for name, value in sorted(counters().items()):
         lines.append(f"{name}: {value:g}")
     traces = retrace_counts()
@@ -282,12 +343,34 @@ def codec_workers() -> int:
     return max(1, min(32, os.cpu_count() or 1))
 
 
+#: ``prctl`` option that sets the calling thread's OS name (linux/prctl.h)
+_PR_SET_NAME = 15
+
+
+def _name_os_thread() -> None:
+    """Give the calling thread its Python name (at most 15 bytes) as its OS
+    name, so a profiler shows each codec worker on a line of its own.  Does
+    nothing off Linux or where libc has no ``prctl``."""
+    if not sys.platform.startswith("linux"):
+        return
+    try:
+        prctl = ctypes.CDLL(None, use_errno=True).prctl
+    except (OSError, AttributeError):
+        return
+    prctl.argtypes = (ctypes.c_int, ctypes.c_char_p, ctypes.c_ulong,
+                      ctypes.c_ulong, ctypes.c_ulong)
+    prctl.restype = ctypes.c_int
+    name = threading.current_thread().name.encode()[:15]
+    prctl(_PR_SET_NAME, name, 0, 0, 0)
+
+
 def _pool() -> ThreadPoolExecutor:
     global _POOL
     with _POOL_LOCK:
         if _POOL is None:
             _POOL = ThreadPoolExecutor(max_workers=codec_workers(),
-                                       thread_name_prefix="repro-codec")
+                                       thread_name_prefix="repro-codec",
+                                       initializer=_name_os_thread)
         return _POOL
 
 
@@ -428,7 +511,7 @@ def run_compress_stage_async(hbae_params: dict, bae_params: list,
     """
     enc = _CACHE.get("encode_frontend", _encode_frontend)
     dec = _CACHE.get("decode_backend", _decode_backend)
-    x = jnp.asarray(hyperblocks)
+    x = to_device(hyperblocks)
     q_lh, q_lbs = enc(hbae_params, bae_params, x, hb_bin, bae_bin)
     recon = dec(hbae_params, bae_params, q_lh, q_lbs, hb_bin, bae_bin)
     return q_lh, q_lbs, recon
@@ -438,7 +521,7 @@ def fetch_compress_stage(handles) -> tuple[np.ndarray, list[np.ndarray],
                                            np.ndarray]:
     """Block until the dispatched front-end finishes and fetch numpy results
     (the per-stripe ``device_get`` half of the double-buffered transfer)."""
-    q_lh, q_lbs, recon = jax.device_get(handles)
+    q_lh, q_lbs, recon = to_host(handles)
     return np.asarray(q_lh), [np.asarray(q) for q in q_lbs], np.asarray(recon)
 
 
@@ -470,8 +553,8 @@ def run_decompress_stage_async(hbae_params: dict, bae_params: list,
     q_lbs = [_as_q32(q) for q in q_lbs]
     if mesh is None:
         dec = _CACHE.get("decode_backend", _decode_backend)
-        return dec(hbae_params, bae_params, jnp.asarray(q_lh),
-                   [jnp.asarray(q) for q in q_lbs], hb_bin, bae_bin)
+        return dec(hbae_params, bae_params, to_device(q_lh),
+                   [to_device(q) for q in q_lbs], hb_bin, bae_bin)
     from jax.sharding import PartitionSpec as P
     shard = P(_mesh_axis())
     dec = _sharded_program(
@@ -487,8 +570,8 @@ def run_recon_stage(hbae_params: dict, bae_params: list,
     """Unquantized AE reconstruction (``reconstruct_ae(quantize_latents=
     False)``)."""
     fn = _CACHE.get("recon_frontend", _recon_frontend)
-    return np.asarray(jax.device_get(
-        fn(hbae_params, bae_params, jnp.asarray(hyperblocks))))
+    return np.asarray(to_host(
+        fn(hbae_params, bae_params, to_device(hyperblocks))))
 
 
 # ---------------------------------------------------------------------------
@@ -523,8 +606,7 @@ def put_sharded(a: np.ndarray, mesh) -> Array:
     """Upload ``a`` split over the mesh's hyper-block axis, each shard's rows
     straight to its own device (no staging on the first device)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
-    return jax.device_put(np.asarray(a),
-                          NamedSharding(mesh, P(_mesh_axis())))
+    return to_device(a, NamedSharding(mesh, P(_mesh_axis())))
 
 
 def run_compress_stage_sharded_async(hbae_params: dict, bae_params: list,
@@ -558,17 +640,10 @@ def run_compress_stage_sharded(hbae_params: dict, bae_params: list,
                                ) -> tuple[np.ndarray, list[np.ndarray],
                                           np.ndarray]:
     """Blocking sharded compress front-end for one shard group; numpy
-    results cover the whole group (callers slice per stripe).  Stage time is
-    recorded under ``ae_encode_sharded`` with ``calls`` = shard count, so
-    ``stage_stats()`` reports per-shard seconds as ``seconds / calls``."""
-    axis = _mesh_axis()
-    n_shards = int(mesh.shape[axis])
-    t0 = time.perf_counter()
+    results cover the whole group (callers slice per stripe)."""
     out = fetch_compress_stage(run_compress_stage_sharded_async(
         hbae_params, bae_params, stacked, hb_bin, bae_bin, mesh))
-    record_stage("ae_encode_sharded", time.perf_counter() - t0,
-                 int(np.asarray(stacked).size), calls=n_shards)
-    counter_max("mesh.shards", n_shards)
+    counter_max("mesh.shards", int(mesh.shape[_mesh_axis()]))
     counter_add("mesh.sharded_groups")
     return out
 

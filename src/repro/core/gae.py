@@ -222,6 +222,11 @@ def gae_encode_blocks(x: np.ndarray, x_r: np.ndarray, basis: np.ndarray,
     extraction are whole-batch numpy passes); the per-block Python work is
     only the namedtuple per code, and the repair rounds touch only blocks
     whose verified error still exceeds ``tau``.
+
+    Stages: ``gae_select`` (upload, selection, fetch of the codes) and
+    ``gae_verify`` (the rest); counters ``gae.blocks``,
+    ``gae.repaired_blocks`` (blocks the repair touched) and
+    ``gae.repair_rounds``.
     """
     from repro.core import exec as exec_mod
 
@@ -230,19 +235,37 @@ def gae_encode_blocks(x: np.ndarray, x_r: np.ndarray, basis: np.ndarray,
     u = np.asarray(basis, np.float32)
     n, d = x.shape
 
-    if jax.default_backend() == "cpu":
-        # host twin: numpy row sorts beat XLA CPU's by a wide margin, and the
-        # encoder is host-side anyway (see select_host)
-        sel = select_host(x - x_r, u, tau, bin_size)
-    else:
-        select = exec_mod.cache().get("gae_select", gae_select,
-                                      static_argnames=("use_kernel",))
-        sel = select(jnp.asarray(x - x_r), jnp.asarray(u), tau, bin_size)
-    # only the codes leave the device: the encoder never trusts the
-    # selection's own ``corrected``/``err``
-    m_sel, order_sel, q_sorted = jax.device_get(
-        (sel.m, sel.order, sel.q_sorted))
+    with exec_mod.stage("gae_select", x.size):
+        if jax.default_backend() == "cpu":
+            # host twin: numpy row sorts beat XLA CPU's by a wide margin, and
+            # the encoder is host-side anyway (see select_host)
+            sel = select_host(x - x_r, u, tau, bin_size)
+        else:
+            select = exec_mod.cache().get("gae_select", gae_select,
+                                          static_argnames=("use_kernel",))
+            sel = select(exec_mod.to_device(x - x_r), exec_mod.to_device(u),
+                         tau, bin_size)
+        # only the codes leave the device: the encoder never trusts the
+        # selection's own ``corrected``/``err``
+        m_sel, order_sel, q_sorted = exec_mod.to_host(
+            (sel.m, sel.order, sel.q_sorted))
+    with exec_mod.stage("gae_verify", x.size):
+        out, codes, repaired, rounds = _verify_codes(
+            x, x_r, u, m_sel, order_sel, q_sorted, tau, bin_size, max_refine)
+    exec_mod.counter_add("gae.blocks", n)
+    exec_mod.counter_add("gae.repaired_blocks", repaired)
+    exec_mod.counter_add("gae.repair_rounds", rounds)
+    return out, codes
 
+
+def _verify_codes(x: np.ndarray, x_r: np.ndarray, u: np.ndarray,
+                  m_sel, order_sel, q_sorted, tau: float, bin_size: float,
+                  max_refine: int
+                  ) -> tuple[np.ndarray, list[GAEBlockCode], int, int]:
+    """The host half of ``gae_encode_blocks``: codes from the selection,
+    verified with the decoder's arithmetic and repaired.  Also returns how
+    many blocks the repair touched and in how many rounds."""
+    n, d = x.shape
     # batch extraction in ascending index order: scatter the kept-coefficient
     # membership and quantized values from sorted-magnitude space back to
     # index space, then one np.nonzero walks every block's set in index order.
@@ -267,7 +290,11 @@ def gae_encode_blocks(x: np.ndarray, x_r: np.ndarray, basis: np.ndarray,
     # coarse-bin fallback), in rounds over the blocks still above tau
     out = _apply_codes(x_r, u, ms, idx_all, q_all, bin_exps, bin_size)
     bad = np.flatnonzero(np.linalg.norm(x - out, axis=1) > tau)
+    repaired = np.zeros(n, bool)
+    rounds = 0
     while bad.size:
+        repaired[bad] = True
+        rounds += 1
         stuck = bad[bin_exps[bad] >= max_refine]
         if stuck.size:
             i = int(stuck[0])
@@ -293,7 +320,7 @@ def gae_encode_blocks(x: np.ndarray, x_r: np.ndarray, basis: np.ndarray,
     be_list = bin_exps.tolist()
     codes = [GAEBlockCode(ms_list[i], idx_list[i], q_list[i], be_list[i])
              for i in range(n)]
-    return out, codes
+    return out, codes, int(repaired.sum()), rounds
 
 
 def _apply_codes(x_r: np.ndarray, u: np.ndarray, ms: np.ndarray,

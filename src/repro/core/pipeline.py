@@ -342,25 +342,28 @@ class HierarchicalCompressor:
                         block=hb_start * gae_per_hb + e.block, err=e.err,
                         tau=e.tau, max_refine=e.max_refine) from e
         with exec_mod.stage("entropy_encode", stripe.size):
-            hb_stream = entropy.huffman_compress(q_lh)
-            bae_streams = [entropy.huffman_compress(q_lb) for q_lb in q_lbs]
-            coeff_stream = None
+            # GAEBlockCode stores indices/coefficients in ascending index
+            # order — exactly the bitmask decode order, no per-code sort
+            all_coeffs, index_sets, binexps = [], [], []
+            for c in codes:
+                index_sets.append(c.indices)
+                all_coeffs.append(c.qcoeffs)
+                binexps.append(c.bin_exp)
+            coeffs = (np.concatenate(all_coeffs) if all_coeffs else
+                      np.zeros(0, np.int64))
+            with exec_mod.stage("huffman_encode", stripe.size):
+                hb_stream = entropy.huffman_compress(q_lh)
+                bae_streams = [entropy.huffman_compress(q_lb)
+                               for q_lb in q_lbs]
+                coeff_stream = (entropy.huffman_compress(coeffs)
+                                if coeffs.size else None)
             index_blob = binexp_blob = b""
             if tau is not None:
-                # GAEBlockCode stores indices/coefficients in ascending index
-                # order — exactly the bitmask decode order, no per-code sort
-                all_coeffs, index_sets, binexps = [], [], []
-                for c in codes:
-                    index_sets.append(c.indices)
-                    all_coeffs.append(c.qcoeffs)
-                    binexps.append(c.bin_exp)
-                coeffs = (np.concatenate(all_coeffs) if all_coeffs else
-                          np.zeros(0, np.int64))
-                if coeffs.size:
-                    coeff_stream = entropy.huffman_compress(coeffs)
-                index_blob = entropy.encode_index_sets(index_sets, gae_dim)
-                binexp_blob = entropy.zlib_pack(
-                    np.asarray(binexps, np.uint8).tobytes())
+                with exec_mod.stage("index_encode", stripe.size):
+                    index_blob = entropy.encode_index_sets(index_sets,
+                                                           gae_dim)
+                    binexp_blob = entropy.zlib_pack(
+                        np.asarray(binexps, np.uint8).tobytes())
         return ArchiveChunk(
             hb_start=hb_start, n_hyperblocks=stripe.shape[0],
             hb_stream=hb_stream, bae_streams=bae_streams,
@@ -520,20 +523,23 @@ class HierarchicalCompressor:
             raise MalformedStream(
                 f"hb stream has {chunk.hb_stream.count} symbols, "
                 f"expected {want_hb}")
-        q_lh = entropy.huffman_decompress(chunk.hb_stream)\
-            .reshape(n_hb, cfg.hb_latent)
-        if len(chunk.bae_streams) != len(self.bae_params):
-            raise MalformedStream(
-                f"{len(chunk.bae_streams)} BAE streams for "
-                f"{len(self.bae_params)} BAE stages")
-        q_lbs = []
-        for stream in chunk.bae_streams:
-            want = n_hb * k * cfg.bae_latent
-            if stream.count != want:
+        n_values = n_hb * k * d
+        with exec_mod.stage("huffman_decode", n_values):
+            q_lh = entropy.huffman_decompress(chunk.hb_stream)\
+                .reshape(n_hb, cfg.hb_latent)
+            if len(chunk.bae_streams) != len(self.bae_params):
                 raise MalformedStream(
-                    f"BAE stream has {stream.count} symbols, expected {want}")
-            q_lbs.append(entropy.huffman_decompress(stream)
-                         .reshape(n_hb * k, cfg.bae_latent))
+                    f"{len(chunk.bae_streams)} BAE streams for "
+                    f"{len(self.bae_params)} BAE stages")
+            q_lbs = []
+            for stream in chunk.bae_streams:
+                want = n_hb * k * cfg.bae_latent
+                if stream.count != want:
+                    raise MalformedStream(
+                        f"BAE stream has {stream.count} symbols, "
+                        f"expected {want}")
+                q_lbs.append(entropy.huffman_decompress(stream)
+                             .reshape(n_hb * k, cfg.bae_latent))
         codes: list[gae.GAEBlockCode] = []
         if chunk.gae_index_blob:
             if archive.gae_dim <= 0:
@@ -543,12 +549,13 @@ class HierarchicalCompressor:
                 raise MalformedStream(
                     f"chunk of {n_hb * k * d} values not divisible into "
                     f"GAE blocks of {d_gae}")
-            n_gae = (n_hb * k * d) // d_gae
-            index_sets = entropy.decode_index_sets(
-                chunk.gae_index_blob, expect_dim=archive.gae_dim,
-                expect_sets=n_gae)
-            binexps = np.frombuffer(
-                entropy.zlib_unpack(chunk.gae_binexp_blob), np.uint8)
+            n_gae = n_values // d_gae
+            with exec_mod.stage("index_decode", n_values):
+                index_sets = entropy.decode_index_sets(
+                    chunk.gae_index_blob, expect_dim=archive.gae_dim,
+                    expect_sets=n_gae)
+                binexps = np.frombuffer(
+                    entropy.zlib_unpack(chunk.gae_binexp_blob), np.uint8)
             if binexps.size != n_gae:
                 raise MalformedStream(
                     f"{binexps.size} bin exponents for {n_gae} GAE blocks")
@@ -559,9 +566,11 @@ class HierarchicalCompressor:
                 raise MalformedStream(
                     f"coefficient stream has {have} values, index sets "
                     f"declare {total}")
-            coeffs = (entropy.huffman_decompress(chunk.gae_coeff_stream)
-                      if chunk.gae_coeff_stream is not None
-                      else np.zeros(0, np.int64))
+            # the stripe's values were counted on its first entry
+            with exec_mod.stage("huffman_decode", 0):
+                coeffs = (entropy.huffman_decompress(chunk.gae_coeff_stream)
+                          if chunk.gae_coeff_stream is not None
+                          else np.zeros(0, np.int64))
             pos = 0
             for i, idx in enumerate(index_sets):
                 codes.append(gae.GAEBlockCode(
@@ -719,7 +728,7 @@ class HierarchicalCompressor:
             [q[a * k:b * k] for q in q_lbs], cfg.hb_bin, cfg.bae_bin, mesh=m)
             for a, b, m in runs]
         recon = np.empty((q_lh.shape[0], k, cfg.block_elems), np.float32)
-        for (a, b, _), part in zip(runs, jax.device_get(handles)):
+        for (a, b, _), part in zip(runs, exec_mod.to_host(handles)):
             recon[a:b] = part
         return recon
 
