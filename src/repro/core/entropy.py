@@ -157,20 +157,39 @@ _VECTOR_DECODE_MIN = 256
 
 
 def _decode_table(book: HuffmanBook) -> tuple[np.ndarray, np.ndarray]:
-    """(table_sym, table_len) 2^16 lookup tables; table_len 0 = invalid."""
-    table_sym = np.zeros(1 << MAX_CODE_LEN, np.int64)
-    table_len = np.zeros(1 << MAX_CODE_LEN, np.uint8)
-    for s, l, c in zip(book.symbols, book.lengths, book.codes):
-        l = int(l)
-        if not 1 <= l <= MAX_CODE_LEN:
-            raise MalformedStream(f"Huffman code length {l} out of range")
-        base = int(c) << (MAX_CODE_LEN - l)
-        span = 1 << (MAX_CODE_LEN - l)
-        if base + span > (1 << MAX_CODE_LEN):
-            raise MalformedStream("Huffman code outside table range")
-        table_sym[base:base + span] = s
-        table_len[base:base + span] = l
-    return table_sym, table_len
+    """(table_sym, table_len) 2^16 lookup tables; table_len 0 = invalid.
+
+    Each code fills the slots of the 16-bit windows it starts, all codes in
+    one ``np.repeat``: the latent streams' books hold thousands of symbols.
+    Codes whose slots overlap are no prefix code and raise."""
+    n = min(book.symbols.size, book.lengths.size, book.codes.size)
+    lengths = book.lengths[:n].astype(np.int64)
+    bad_len = (lengths < 1) | (lengths > MAX_CODE_LEN)
+    shift = MAX_CODE_LEN - np.clip(lengths, 1, MAX_CODE_LEN)
+    base = book.codes[:n].astype(np.int64) << shift
+    span = np.left_shift(1, shift)
+    bad = bad_len | (base + span > 1 << MAX_CODE_LEN)
+    if bad.any():
+        i = int(np.argmax(bad))
+        if bad_len[i]:
+            raise MalformedStream(
+                f"Huffman code length {int(lengths[i])} out of range")
+        raise MalformedStream("Huffman code outside table range")
+    # the codes' slot ranges in order, with the undecodable gaps between
+    # them: gap, code, gap, code, ..., gap
+    order = np.argsort(base, kind="stable")
+    base, span = base[order], span[order]
+    ends = np.concatenate([[0], base + span])
+    gaps = np.append(base, 1 << MAX_CODE_LEN) - ends
+    if np.any(gaps < 0):
+        raise MalformedStream("Huffman codes overlap: not a prefix code")
+    counts = np.empty(2 * n + 1, np.int64)
+    counts[0::2], counts[1::2] = gaps, span
+    syms = np.zeros(2 * n + 1, np.int64)
+    syms[1::2] = book.symbols[:n][order]
+    lens = np.zeros(2 * n + 1, np.uint8)
+    lens[1::2] = lengths[order]
+    return np.repeat(syms, counts), np.repeat(lens, counts)
 
 
 def _decode_prologue(data: bytes, book: HuffmanBook, count: int):
@@ -215,11 +234,12 @@ def huffman_decode(data: bytes, book: HuffmanBook, count: int) -> np.ndarray:
     Large streams take a vectorized path: every bit position's (symbol, step)
     is computed in one numpy pass, then the decode chain pos -> pos + step is
     enumerated by pointer doubling — O(total_bits * log(count)) numpy work
-    with no per-symbol Python iteration, and GIL-releasing so independent
-    chunks decode in parallel (see ``core.exec.map_parallel``).  Output and
-    typed-error behavior are identical to ``huffman_decode_scalar`` (the
-    chain is deterministic up to the first damaged position, which is
-    reported exactly as the scalar loop would).
+    with no per-symbol Python iteration.  Output and typed-error behavior
+    are identical to ``huffman_decode_scalar`` (the chain is deterministic
+    up to the first damaged position, which is reported exactly as the
+    scalar loop would).  The numpy calls hold the interpreter lock between
+    them, and many work on small arrays, so chunks decoded side by side on
+    the codec pool (``core.exec.map_parallel``) run largely in turn.
     """
     if count == 0:
         return np.zeros(0, np.int64)

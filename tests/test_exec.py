@@ -289,40 +289,188 @@ def test_nonstrict_decode_bit_identical_on_undamaged_archive(comp_hb):
 # vectorized codec twins vs their scalar oracles
 # ---------------------------------------------------------------------------
 
-def test_huffman_vector_decode_matches_scalar():
-    rng = np.random.default_rng(4)
-    for n in (300, 1000, 5000):   # all above _VECTOR_DECODE_MIN
-        vals = rng.geometric(0.3, size=n).astype(np.int64) - 3
-        book = entropy.build_huffman(vals)
-        data = entropy.huffman_encode(vals, book)
-        fast = entropy.huffman_decode(data, book, n)
-        slow = entropy.huffman_decode_scalar(data, book, n)
-        np.testing.assert_array_equal(fast, slow)
-        np.testing.assert_array_equal(fast, vals)
+def _laplace(n, scale, seed):
+    rng = np.random.default_rng(seed)
+    return np.round(rng.laplace(0.0, scale, n)).astype(np.int64)
 
 
-def test_huffman_vector_decode_matches_scalar_on_corruption():
-    rng = np.random.default_rng(5)
-    vals = rng.geometric(0.4, size=800).astype(np.int64)
+def _dyadic(n_lengths):
+    """Counts 2^(n-1), ..., 2, 1, 1: a book with every length 1..n."""
+    counts = [1 << (n_lengths - 1 - k) for k in range(n_lengths)] + [1]
+    vals = np.repeat(np.arange(len(counts)), counts)
+    return np.random.default_rng(9).permutation(vals).astype(np.int64)
+
+
+# A power-of-two span of bits: streams made of whole spans, and bit flips
+# on the spans' edges, where a window reads across a byte-triple boundary.
+_SEG = 2048
+
+HUFFMAN_STREAMS = {
+    "laplace-256": _laplace(256, 2.0, 1),
+    "geometric-300": np.random.default_rng(4).geometric(0.3, 300) - 3,
+    "geometric-5000": np.random.default_rng(4).geometric(0.3, 5000) - 3,
+    "laplace-wide-4096": _laplace(4096, 40.0, 2),
+    "laplace-200k": _laplace(200_000, 3.0, 3),
+    "lengths-1-to-16": _dyadic(16),
+    "single-symbol": np.full(1000, 7, np.int64),
+    # a fixed-length code whose length does not divide the span
+    "fixed-3-bit": np.tile(np.arange(8), 1000),
+    # 4-bit codes filling whole spans: the stream ends on a span's end
+    "segments-exact": np.tile(np.arange(16), _SEG // 16),
+}
+
+
+@pytest.mark.parametrize("name", sorted(HUFFMAN_STREAMS))
+def test_huffman_vector_decode_matches_scalar(name):
+    vals = HUFFMAN_STREAMS[name]
+    n = vals.size
+    assert n >= entropy._VECTOR_DECODE_MIN
     book = entropy.build_huffman(vals)
-    data = bytearray(entropy.huffman_encode(vals, book))
-    for _ in range(20):
-        pos = int(rng.integers(len(data)))
-        bit = 1 << int(rng.integers(8))
-        data[pos] ^= bit
-        fast_err = slow_err = fast = slow = None
+    data = entropy.huffman_encode(vals, book)
+    if name == "lengths-1-to-16":
+        assert sorted(set(book.lengths.tolist())) == list(range(1, 17))
+    if name == "fixed-3-bit":
+        assert set(book.lengths.tolist()) == {3} and _SEG % 3
+    if name == "segments-exact":
+        assert len(data) * 8 == 4 * _SEG
+    fast = entropy.huffman_decode(data, book, n)
+    slow = entropy.huffman_decode_scalar(data, book, n)
+    np.testing.assert_array_equal(fast, slow)
+    np.testing.assert_array_equal(fast, vals)
+
+
+def _decode_both(data, book, count):
+    """(values or None, (error type, message) or None) of both decoders."""
+    got = []
+    for decode in (entropy.huffman_decode, entropy.huffman_decode_scalar):
         try:
-            fast = entropy.huffman_decode(bytes(data), book, 800)
+            got.append((decode(bytes(data), book, count), None))
         except (MalformedStream, entropy.TruncatedArchive) as e:
-            fast_err = (type(e), str(e))
-        try:
-            slow = entropy.huffman_decode_scalar(bytes(data), book, 800)
-        except (MalformedStream, entropy.TruncatedArchive) as e:
-            slow_err = (type(e), str(e))
-        assert fast_err == slow_err
-        if fast_err is None:
-            np.testing.assert_array_equal(fast, slow)
-        data[pos] ^= bit   # restore
+            got.append((None, (type(e), str(e))))
+    return got
+
+
+def _flipped(data, bits):
+    out = bytearray(data)
+    for b in bits:
+        out[b >> 3] ^= 0x80 >> (b & 7)
+    return out
+
+
+def _corruptions():
+    rng = np.random.default_rng(5)
+    n = 3 * _SEG // 2                  # about three spans of codes
+    vals = rng.geometric(0.4, size=n).astype(np.int64)
+    book = entropy.build_huffman(vals)
+    data = entropy.huffman_encode(vals, book)
+    assert len(data) * 8 > 2 * _SEG + 1
+    cases = [(f"random-{i}", data, [int(rng.integers(len(data) * 8))], book,
+              n) for i in range(20)]
+    edges = [k * _SEG + d for k in (1, 2) for d in (-1, 0, 1)]
+    cases += [(f"boundary-{b}", data, [b], book, n) for b in edges]
+    cases.append(("boundary-all", data, edges, book, n))
+    big = _laplace(20_000, 3.0, 6)
+    big_book = entropy.build_huffman(big)
+    big_data = entropy.huffman_encode(big, big_book)
+    assert len(big_data) * 4 > _SEG
+    cases.append(("truncated", big_data[:len(big_data) // 2], [], big_book,
+                  big.size))
+    cases.append(("truncated-empty", b"", [], big_book, big.size))
+    # a one-symbol book leaves half the windows undecodable
+    one = np.zeros(4 * _SEG, np.int64)
+    one_book = entropy.build_huffman(one)
+    one_data = entropy.huffman_encode(one, one_book)
+    cases += [(f"undecodable-{b}", one_data, [b], one_book, one.size)
+              for b in (5, _SEG - 1, _SEG, 3 * _SEG + 1)]
+    # the payload holds more codes than are asked for: the chain is broken
+    # long before its end
+    cases.append(("undecodable-early", one_data, [7], one_book, 2 * _SEG))
+    # the last code asked for runs past the payload's end
+    tail = _dyadic(16)
+    tail_book = entropy.build_huffman(tail)
+    longest = tail_book.symbols[-1]         # a 16-bit code, moved to the end
+    assert tail_book.lengths[-1] == 16
+    tail = np.append(np.delete(tail, np.flatnonzero(tail == longest)[0]),
+                     longest)
+    cases.append(("truncated-last-code",
+                  entropy.huffman_encode(tail, tail_book)[:-1], [], tail_book,
+                  tail.size))
+    return cases
+
+
+CORRUPTIONS = {c[0]: c[1:] for c in _corruptions()}
+
+
+@pytest.mark.parametrize("name", list(CORRUPTIONS))
+def test_huffman_vector_decode_matches_scalar_on_corruption(name):
+    data, bits, book, count = CORRUPTIONS[name]
+    (fast, fast_err), (slow, slow_err) = _decode_both(
+        _flipped(data, bits), book, count)
+    assert fast_err == slow_err
+    if fast_err is None:
+        np.testing.assert_array_equal(fast, slow)
+    if name.startswith("undecodable"):
+        assert fast_err == (MalformedStream,
+                            f"undecodable Huffman prefix at bit {bits[0]}")
+    if name.startswith("truncated"):
+        assert fast_err[0] is entropy.TruncatedArchive
+
+
+def _table_by_code(book):
+    """The decode table filled one code at a time: the oracle of
+    ``entropy._decode_table``."""
+    table_sym = np.zeros(1 << 16, np.int64)
+    table_len = np.zeros(1 << 16, np.uint8)
+    for sym, length, code in zip(book.symbols, book.lengths, book.codes):
+        length = int(length)
+        if not 1 <= length <= 16:
+            raise MalformedStream(f"Huffman code length {length} out of range")
+        base, span = int(code) << (16 - length), 1 << (16 - length)
+        if base + span > 1 << 16:
+            raise MalformedStream("Huffman code outside table range")
+        table_sym[base:base + span] = sym
+        table_len[base:base + span] = length
+    return table_sym, table_len
+
+
+def _book(symbols, lengths, codes):
+    return entropy.HuffmanBook(np.array(symbols, np.int64),
+                               np.array(lengths, np.uint8),
+                               np.array(codes, np.uint32))
+
+
+DECODE_TABLE_BOOKS = {
+    "laplace-narrow": entropy.build_huffman(_laplace(5000, 1.0, 11)),
+    "laplace-wide": entropy.build_huffman(_laplace(20000, 3000.0, 12)),
+    "lengths-1-to-16": entropy.build_huffman(_dyadic(16)),
+    "single-symbol": entropy.build_huffman(np.full(10, 3)),
+    "incomplete": _book([5, 9], [2, 3], [0b01, 0b110]),
+    "unsorted": _book([5, 9, 4], [3, 1, 2], [0b110, 0b0, 0b10]),
+    "empty": _book([], [], []),
+    "length-0": _book([1, 2], [1, 0], [0, 1]),
+    "length-17": _book([1, 2], [1, 17], [0, 1]),
+    "outside": _book([1, 2], [2, 1], [0b1001, 0]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(DECODE_TABLE_BOOKS))
+def test_decode_table_matches_code_by_code_fill(name):
+    book = DECODE_TABLE_BOOKS[name]
+    try:
+        want = _table_by_code(book)
+    except MalformedStream as e:
+        with pytest.raises(MalformedStream, match=f"^{str(e)}$"):
+            entropy._decode_table(book)
+        return
+    got = entropy._decode_table(book)
+    np.testing.assert_array_equal(got[0], want[0])
+    np.testing.assert_array_equal(got[1], want[1])
+
+
+def test_decode_table_rejects_codes_that_are_not_a_prefix_code():
+    # "0" is a prefix of "01": one window would decode as both
+    with pytest.raises(MalformedStream, match="not a prefix code"):
+        entropy._decode_table(_book([1, 2], [1, 2], [0b0, 0b01]))
 
 
 def test_index_set_codec_roundtrip_with_empty_sets():
