@@ -2,6 +2,7 @@
 """Run the paper's compressor end to end on a TPU and check what comes out.
 
     python chip_smoke.py            # one chip: E3SM at paper size, full width
+    python chip_smoke.py --dataset s3d   # one chip: S3D at paper size
     python chip_smoke.py --mesh 4   # four chips: sharded compress/decompress
                                     # against the single-device compress
 
@@ -12,7 +13,10 @@ drives: ``compress``, ``stream_compress`` into an ``.rba``, ``decompress``,
 ``read_archive`` of that file and a second ``decompress``.  It fails unless
 every GAE block is within tau on both decodes, the streamed container is
 byte-identical to the batch archive, the disk round trip decodes bit-exactly,
-and no stripe was quarantined, retried or failed over.
+and no stripe was quarantined, retried or failed over.  ``--dataset s3d``
+does the same for S3D at its paper size (58 species x 50 x 640 x 640,
+25,600 hyper-blocks of 10 x 4640, GAE per species at D = 80) with the
+full-width ``configs/s3d.py`` model.
 
 ``--mesh 4`` fits the same model, compresses once on one device and once
 with ``CompressOptions(mesh=4)``, and decodes each; it fails unless the two
@@ -38,10 +42,12 @@ ROOT = Path(__file__).resolve().parent
 sys.path.insert(0, str(ROOT / "src"))
 
 DATASET = "e3sm"
-#: below the CLI default of 0.5, which the full-width E3SM model meets with
-#: the AE alone on all but a handful of blocks (max l2 0.484 after 3 epochs,
-#: one TPU v5e): at 0.2 GAE codes a clear share of the blocks
-TAU = 0.2
+#: per GAE block.  E3SM: below the CLI default of 0.5, which the full-width
+#: model meets with the AE alone on all but a handful of blocks (max l2
+#: 0.484 after 3 epochs, one TPU v5e): at 0.2 GAE codes a clear share of the
+#: blocks.  S3D: an RMS error of 0.5% of each species' range over D = 80
+TAUS = {"e3sm": 0.2, "s3d": 0.005 * 80 ** 0.5}
+TAU = TAUS[DATASET]
 #: the one cut: 30 -> 3 epochs for both HBAE and BAE (widths unchanged)
 EPOCHS_SCALE = 0.1
 OUT_DIR = ROOT / ".chip_smoke"
@@ -220,6 +226,8 @@ def _cache_entries(path: str) -> int:
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    ap.add_argument("--dataset", choices=sorted(TAUS), default=DATASET,
+                    help="the paper's dataset to run at its size")
     ap.add_argument("--mesh", type=int, default=None, metavar="N",
                     help="run only the N-chip sharded compress/decompress "
                     "and the single-device compress it is compared with")
@@ -245,10 +253,11 @@ def main(argv=None) -> int:
          f"30 -> {max(1, int(30 * EPOCHS_SCALE))} epochs); data size and "
          f"model widths are the paper's")
     try:
+        tau = TAUS[args.dataset]
         if args.mesh:
-            run_mesh(args.mesh)
+            run_mesh(args.mesh, args.dataset, tau=tau)
         else:
-            run_single_chip()
+            run_single_chip(args.dataset, tau=tau)
     except SmokeFailure as e:
         print(f"FAIL: {e}", file=sys.stderr)
         return 1

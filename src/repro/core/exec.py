@@ -55,6 +55,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import bae as bae_mod
+from repro.core import gae
 from repro.core import hbae as hbae_mod
 from repro.core.errors import TransientStageError
 from repro.core.quantization import dequantize, quantize
@@ -477,20 +478,6 @@ def _decode_backend(hbae_params: dict, bae_params: list, q_lh: Array,
     return recon
 
 
-def _recon_frontend(hbae_params: dict, bae_params: list, x: Array) -> Array:
-    """AE reconstruction WITHOUT latent quantization (ablation path)."""
-    y, _ = hbae_mod.hbae_apply(hbae_params, x)
-    recon = y
-    if bae_params:
-        n, k, d = x.shape
-        resid = (x - y).reshape(n * k, d)
-        for p in bae_params:
-            r_hat, _ = bae_mod.bae_apply(p, resid)
-            recon = recon + r_hat.reshape(n, k, d)
-            resid = resid - r_hat
-    return recon
-
-
 def _as_q32(q: np.ndarray) -> np.ndarray:
     """Entropy-decoded latents arrive int64; the device programs trace on the
     int32 the quantizer emits — cast host-side so the trace cache hits."""
@@ -565,13 +552,43 @@ def run_decompress_stage_async(hbae_params: dict, bae_params: list,
                [put_sharded(q, mesh) for q in q_lbs], hb_bin, bae_bin)
 
 
-def run_recon_stage(hbae_params: dict, bae_params: list,
-                    hyperblocks: np.ndarray) -> np.ndarray:
-    """Unquantized AE reconstruction (``reconstruct_ae(quantize_latents=
-    False)``)."""
-    fn = _CACHE.get("recon_frontend", _recon_frontend)
-    return np.asarray(to_host(
-        fn(hbae_params, bae_params, to_device(hyperblocks))))
+def _add_residual_covariance(cov: Array, x: Array, recon: Array) -> Array:
+    """``cov`` plus the covariance of one stripe's residual ``x - recon``,
+    cut into GAE blocks of ``cov``'s dimension."""
+    return cov + gae.residual_covariance((x - recon).reshape(-1, cov.shape[0]))
+
+
+def _add_residual_covariance_psum(cov: Array, x: Array, recon: Array
+                                  ) -> Array:
+    """The same over a shard group: each shard's covariance, ``psum``-ed."""
+    return cov + gae.residual_covariance(
+        (x - recon).reshape(-1, cov.shape[0]), axis_name=_mesh_axis())
+
+
+def run_basis_stage_async(hbae_params: dict, bae_params: list,
+                          hyperblocks: np.ndarray, hb_bin: float,
+                          bae_bin: float, cov: Array, mesh=None) -> Array:
+    """Add the GAE residual covariance of ONE stripe, or with a ``mesh`` of
+    one shard group (one stripe per shard), to the device-resident ``cov``
+    (D_gae, D_gae), without blocking.  The residual is the stripe minus the
+    reconstruction ``run_compress_stage`` (``run_compress_stage_sharded``)
+    computes for it, so the basis is fitted on the residuals compress codes,
+    by the programs compress runs."""
+    if mesh is None:
+        x = to_device(hyperblocks)
+        _, _, recon = run_compress_stage_async(hbae_params, bae_params, x,
+                                               hb_bin, bae_bin)
+        add = _CACHE.get("residual_covariance", _add_residual_covariance)
+        return add(cov, x, recon)
+    from jax.sharding import PartitionSpec as P
+    shard = P(_mesh_axis())
+    x = put_sharded(hyperblocks, mesh)
+    _, _, recon = run_compress_stage_sharded_async(hbae_params, bae_params, x,
+                                                   hb_bin, bae_bin, mesh)
+    add = _sharded_program("residual_covariance",
+                           _add_residual_covariance_psum, mesh,
+                           (P(), shard, shard), P())
+    return add(cov, x, recon)
 
 
 # ---------------------------------------------------------------------------

@@ -19,10 +19,11 @@ Two implementations, proven equivalent by tests:
   one comparison — branch-free and batched.  This replaces the paper's serial
   re-quantize/re-reconstruct loop (GPU/CPU-style) with a one-shot form.
 
-Distribution: ``fit_pca_basis(..., axis_name=...)`` computes the residual
-covariance locally and ``psum``s the D x D matrix across the data axis, so the
-basis is exact over the global dataset with O(D^2) communication independent of
-dataset size.
+Scale: the basis comes from the D x D residual covariance
+(``residual_covariance``), which adds up over disjoint sets of blocks, so the
+compressor sums it stripe by stripe on the device (``psum``-ed over the data
+axis of a mesh, O(D^2) communication independent of dataset size) and runs one
+``eigh`` on the sum (``pca_basis``).
 """
 from __future__ import annotations
 
@@ -42,19 +43,31 @@ Array = jax.Array
 # PCA basis
 # ---------------------------------------------------------------------------
 
-def fit_pca_basis(residuals: Array, axis_name: Optional[str] = None) -> Array:
-    """PCA basis of block residuals.
-
-    residuals: (N, D).  Returns U (D, D) with eigenvectors as COLUMNS, sorted
-    by descending eigenvalue; coefficients are c = U^T r (paper Eq. 9).
-    """
+def residual_covariance(residuals: Array,
+                        axis_name: Optional[str] = None) -> Array:
+    """``r.T @ r`` (D, D) of (N, D) block residuals in float32; with
+    ``axis_name``, ``psum``-ed over that axis.  Covariances of disjoint sets
+    of blocks add up to the covariance of their union, so a basis can be
+    fitted on a field stripe by stripe."""
     r = residuals.astype(jnp.float32)
-    cov = r.T @ r                                     # (D, D)
+    cov = r.T @ r
     if axis_name is not None:
         cov = jax.lax.psum(cov, axis_name)
+    return cov
+
+
+def pca_basis(cov: Array) -> Array:
+    """PCA basis of a residual covariance: U (D, D) with eigenvectors as
+    COLUMNS, sorted by descending eigenvalue; coefficients are c = U^T r
+    (paper Eq. 9)."""
     # eigh returns ascending eigenvalues; flip to descending.
     _, vecs = jnp.linalg.eigh(cov)
     return vecs[:, ::-1]
+
+
+def fit_pca_basis(residuals: Array, axis_name: Optional[str] = None) -> Array:
+    """PCA basis of (N, D) block residuals held in one array."""
+    return pca_basis(residual_covariance(residuals, axis_name))
 
 
 # ---------------------------------------------------------------------------

@@ -36,6 +36,11 @@ from repro.core.options import CompressOptions, resolve_options
 
 Array = jax.Array
 
+#: Stripe width in hyper-blocks that ``fit`` and ``fit_basis`` run at unless
+#: told otherwise: compress's own default, so the basis is fitted on the
+#: stripes compress codes.
+STRIPE_HYPERBLOCKS = CompressOptions.chunk_hyperblocks
+
 #: Sentinel distinguishing "kwarg not passed" from an explicit ``None`` on
 #: the deprecated ``compress(tau=..., chunk_hyperblocks=...)`` surface.
 _UNSET = object()
@@ -194,6 +199,12 @@ class HierarchicalCompressor:
     # -- training ----------------------------------------------------------
     def fit(self, hyperblocks: np.ndarray, seed: int = 0,
             log: Optional[Callable] = None) -> "HierarchicalCompressor":
+        """Train the HBAE on ``hyperblocks``, then each BAE stage on the
+        residual the stages before it leave.  The training steps gather
+        their batches from the whole field held on the device; the
+        residuals are computed stripe by stripe (``STRIPE_HYPERBLOCKS``
+        wide, span ``fit_forward``), so no forward program holds the
+        field."""
         cfg = self.cfg
         n, k, d = hyperblocks.shape
         assert k == cfg.k and d == cfg.block_elems, (hyperblocks.shape, cfg)
@@ -203,63 +214,99 @@ class HierarchicalCompressor:
             khb, hyperblocks, emb=cfg.emb, hidden=cfg.hidden, latent=cfg.hb_latent,
             heads=cfg.heads, use_attention=cfg.use_attention,
             epochs=cfg.epochs_hbae, batch=cfg.batch, lr=cfg.lr, seed=seed, log=log)
-        if cfg.use_bae:
-            y, _ = self._hbae_forward(hyperblocks)
-            resid = (hyperblocks - y).reshape(n * k, d)
-            self.bae_params = []
-            for s in range(cfg.n_bae_stages):
-                p = training.train_bae(kbs[s], resid, hidden=cfg.bae_hidden,
-                                       latent=cfg.bae_latent, epochs=cfg.epochs_bae,
-                                       batch=max(cfg.batch * 4, 256), lr=cfg.lr,
-                                       seed=seed + s, log=log)
-                self.bae_params.append(p)
-                apply_fn = exec_mod.cache().get("bae_apply", bae_mod.bae_apply)
-                r_hat, _ = apply_fn(p, jnp.asarray(resid))
-                resid = resid - np.asarray(r_hat)
+        self.bae_params = []
+        if not cfg.use_bae:
+            return self
+        spans = self.stripe_spans(n, STRIPE_HYPERBLOCKS, with_gae=False)
+        resid = np.empty((n * k, d), np.float32)
+        hbae_fn = exec_mod.cache().get("hbae_apply", hbae_mod.hbae_apply)
+        with exec_mod.stage("fit_forward", hyperblocks.size):
+            for s, w in spans:
+                x = hyperblocks[s:s + w]
+                y, _ = hbae_fn(self.hbae_params, exec_mod.to_device(x))
+                resid[s * k:(s + w) * k] = (x - exec_mod.to_host(y)).reshape(
+                    w * k, d)
+                exec_mod.counter_add("fit.stripes")
+        bae_fn = exec_mod.cache().get("bae_apply", bae_mod.bae_apply)
+        for st in range(cfg.n_bae_stages):
+            p = training.train_bae(kbs[st], resid, hidden=cfg.bae_hidden,
+                                   latent=cfg.bae_latent, epochs=cfg.epochs_bae,
+                                   batch=max(cfg.batch * 4, 256), lr=cfg.lr,
+                                   seed=seed + st, log=log)
+            self.bae_params.append(p)
+            if st + 1 == cfg.n_bae_stages:
+                break
+            # the next stage trains on what this one leaves
+            with exec_mod.stage("fit_forward", resid.size):
+                for s, w in spans:
+                    rows = resid[s * k:(s + w) * k]
+                    r_hat, _ = bae_fn(p, exec_mod.to_device(rows))
+                    rows -= exec_mod.to_host(r_hat)
+                    exec_mod.counter_add("fit.stripes")
         return self
 
     # -- forward helpers ----------------------------------------------------
     def _stage_params(self) -> list[dict]:
         return self.bae_params if self.cfg.use_bae else []
 
-    def _hbae_forward(self, hyperblocks: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
-        apply_fn = exec_mod.cache().get("hbae_apply", hbae_mod.hbae_apply)
-        y, latent = apply_fn(self.hbae_params, jnp.asarray(hyperblocks))
-        return np.asarray(y), np.asarray(latent)
-
-    def reconstruct_ae(self, hyperblocks: np.ndarray,
-                       quantize_latents: bool = True) -> np.ndarray:
-        """AE-only reconstruction (through quantized latents when requested)."""
-        cfg = self.cfg
-        if quantize_latents:
-            # same fused front-end + shared decode program as ``compress``
-            _, _, recon = exec_mod.run_compress_stage(
-                self.hbae_params, self._stage_params(), hyperblocks,
-                cfg.hb_bin, cfg.bae_bin)
-            return recon
-        return exec_mod.run_recon_stage(self.hbae_params, self._stage_params(),
-                                        hyperblocks)
-
     # -- PCA basis -----------------------------------------------------------
-    def fit_basis(self, hyperblocks: np.ndarray, mesh=None) -> np.ndarray:
-        """PCA basis of AE residuals at GAE block granularity.
+    def fit_basis(self, hyperblocks: np.ndarray, mesh=None,
+                  chunk_hyperblocks: int = STRIPE_HYPERBLOCKS) -> np.ndarray:
+        """PCA basis of the AE residuals at GAE block granularity.
 
-        With a ``mesh`` (anything ``parallel.mesh_exec.resolve_mesh``
-        accepts) the D x D residual covariance is computed shard-locally and
-        ``psum``-ed over the hyper-block axis — O(D^2) communication
-        regardless of N — via ``gae.fit_pca_basis(axis_name=...)``.
+        The field runs stripe by stripe, on the stripes ``compress`` cuts at
+        ``chunk_hyperblocks`` and through the programs it runs on them, so
+        the basis is fitted on exactly the residuals compress codes.  Each
+        stripe's D x D residual covariance is added up on the device in
+        float32 and one ``eigh`` of the sum gives the basis (span
+        ``basis_fit``).  With a ``mesh`` (anything
+        ``parallel.mesh_exec.resolve_mesh`` accepts) the aligned groups of
+        stripes run one per shard, as compress runs them, and their
+        covariances are ``psum``-ed over the hyper-block axis.
         """
-        recon = self.reconstruct_ae(hyperblocks)
-        resid = self._gae_view(hyperblocks - recon)
+        with exec_mod.stage("basis_fit", hyperblocks.size):
+            cov = self.residual_covariance(hyperblocks, mesh,
+                                           chunk_hyperblocks)
+            self.basis = np.asarray(gae.pca_basis(jnp.asarray(cov)))
+        return self.basis
+
+    def residual_covariance(self, hyperblocks: np.ndarray, mesh=None,
+                            chunk_hyperblocks: int = STRIPE_HYPERBLOCKS
+                            ) -> np.ndarray:
+        """The (D_gae, D_gae) float32 covariance ``fit_basis`` diagonalizes:
+        ``r.T @ r`` over every GAE block's AE residual ``r``, summed stripe
+        by stripe on the device."""
+        cfg = self.cfg
+        d_gae = cfg.gae_block_elems or cfg.block_elems
+        spans = self.stripe_spans(hyperblocks.shape[0], chunk_hyperblocks,
+                                  with_gae=True)
+        runs = [(s, s + w, None) for s, w in spans]
+        resolved = None
         if mesh is not None:
             from repro.parallel import mesh_exec
             resolved = mesh_exec.resolve_mesh(mesh)
-            if resolved is not None:
-                self.basis = np.asarray(
-                    mesh_exec.fit_pca_basis_sharded(resid, resolved))
-                return self.basis
-        self.basis = np.asarray(gae.fit_pca_basis(jnp.asarray(resid)))
-        return self.basis
+        if resolved is not None:
+            groups, tail = mesh_exec.plan_shard_groups(
+                spans, mesh_exec.mesh_shards(resolved))
+            runs = ([(*mesh_exec.group_slice(g), resolved) for g in groups]
+                    + [(s, s + w, None) for s, w in tail])
+        zero = np.zeros((d_gae, d_gae), np.float32)
+        # one sum per placement: the shard groups' (replicated over the
+        # mesh) and the single-device stripes'
+        sums: dict = {}
+        for a, b, m in runs:
+            if m not in sums:
+                sums[m] = (exec_mod.to_device(zero) if m is None else
+                           exec_mod.to_device(zero, mesh_exec.replicated(m)))
+            prev = sums[m]
+            sums[m] = exec_mod.run_basis_stage_async(
+                self.hbae_params, self._stage_params(), hyperblocks[a:b],
+                cfg.hb_bin, cfg.bae_bin, prev, mesh=m)
+            # at most two stripes in flight: the device never holds more
+            prev.block_until_ready()
+            exec_mod.counter_add("fit.stripes")
+        return sum(np.asarray(c, np.float32)
+                   for c in exec_mod.to_host(list(sums.values())))
 
     def _gae_view(self, blocks3d: np.ndarray) -> np.ndarray:
         """(N, k, D) -> (N_gae, D_gae): GAE may use a different block size."""
@@ -397,13 +444,15 @@ class HierarchicalCompressor:
             chunk.n_hyperblocks, cfg.k, cfg.block_elems).copy()
 
     def prepare_compress(self, hyperblocks: np.ndarray, tau: Optional[float],
-                         mesh=None) -> int:
+                         mesh=None,
+                         chunk_hyperblocks: int = STRIPE_HYPERBLOCKS) -> int:
         """Shared compress preamble: fit the PCA basis if the caller asked
-        for a guarantee and none exists yet (sharded over ``mesh`` when one
-        is active).  Returns ``gae_dim``."""
+        for a guarantee and none exists yet (on compress's stripes, sharded
+        over ``mesh`` when one is active).  Returns ``gae_dim``."""
         if tau is not None:
             if self.basis is None:
-                self.fit_basis(hyperblocks, mesh=mesh)
+                self.fit_basis(hyperblocks, mesh=mesh,
+                               chunk_hyperblocks=chunk_hyperblocks)
             return int(self.basis.shape[0])
         return 0
 
@@ -460,7 +509,8 @@ class HierarchicalCompressor:
         if opts.mesh is not None:
             from repro.parallel import mesh_exec
             mesh = mesh_exec.resolve_mesh(opts.mesh)
-        gae_dim = self.prepare_compress(hyperblocks, tau, mesh=mesh)
+        gae_dim = self.prepare_compress(hyperblocks, tau, mesh=mesh,
+                                        chunk_hyperblocks=opts.chunk_hyperblocks)
         spans = self.stripe_spans(n, opts.chunk_hyperblocks,
                                   with_gae=tau is not None)
 

@@ -76,16 +76,23 @@ def s3d_like(n_species: int = 58, t: int = 50, h: int = 640, w: int = 640,
     latents = np.stack([_fourier_field(rng, t, h, w) for _ in range(rank)])  # (r,T,H,W)
     mix = rng.normal(size=(n_species, rank)).astype(np.float32)
     mix /= np.linalg.norm(mix, axis=1, keepdims=True)
-    base = np.tensordot(mix, latents, axes=(1, 0))                           # (S,T,H,W)
+    out = np.tensordot(mix, latents, axes=(1, 0))                            # (S,T,H,W)
     # per-species monotone nonlinearity (species concentrations are positive,
-    # exponentially distributed in magnitude like ignition chemistry)
+    # exponentially distributed in magnitude like ignition chemistry), in
+    # place over the mixture
     gains = rng.uniform(0.5, 2.0, size=n_species).astype(np.float32)
     scales = np.exp(rng.uniform(-3, 3, size=n_species)).astype(np.float32)
-    out = np.empty_like(base)
     for s in range(n_species):
-        out[s] = scales[s] * np.exp(gains[s] * np.tanh(base[s]))
-    out += noise * rng.standard_normal(out.shape).astype(np.float32) * out.std()
-    return out.astype(np.float32)
+        out[s] = scales[s] * np.exp(gains[s] * np.tanh(out[s]))
+    # the noise drawn in slabs: the same draws, in the same order, as one
+    # draw of the whole shape, without its float64 temporaries
+    spread = out.std()
+    flat = out.reshape(-1)
+    for i in range(0, flat.size, 1 << 24):
+        n = min(1 << 24, flat.size - i)
+        flat[i:i + n] += (noise * rng.standard_normal(n).astype(np.float32)
+                          * spread)
+    return out.astype(np.float32, copy=False)
 
 
 # ---------------------------------------------------------------------------

@@ -15,8 +15,9 @@ basis — the same construction the unit tests use):
    bytes again, in memory AND on disk;
 3. **zero retraces** — a second sharded+unsharded compress pass triggers no
    new traces (the mesh-keyed ``JitCache`` keeps both program sets live);
-4. **psum basis** — the shard_map'd PCA fit matches the single-device basis
-   to float32 tolerance (psum order may differ in the last ulp);
+4. **psum basis** — the residual covariance summed over shard groups
+   matches the single-device stripe-by-stripe sum, and so do the bases, to
+   float32 tolerance (the order of the sum differs);
 5. **sharded decompress** — the mesh decode back-end reproduces the
    single-device reconstruction within float32 tolerance and the tau
    guarantee holds on every GAE block;
@@ -52,7 +53,6 @@ import jax                                                  # noqa: E402
 from repro.core import CompressorConfig, HierarchicalCompressor  # noqa: E402
 from repro.core import bae as bae_mod                       # noqa: E402
 from repro.core import exec as exec_mod                     # noqa: E402
-from repro.core import gae                                  # noqa: E402
 from repro.core import hbae as hbae_mod                     # noqa: E402
 from repro.core.options import CompressOptions              # noqa: E402
 from repro.parallel import mesh_exec                        # noqa: E402
@@ -141,26 +141,31 @@ def main() -> int:
     check("zero_retraces_after_warmup", delta == 0,
           f"delta={delta} counts={exec_mod.retrace_counts()}")
 
-    # psum basis: needs a FULL-RANK covariance (rows >> dims) — on a
-    # rank-deficient one the null-space eigenvectors are arbitrary and no
-    # comparison is meaningful.  Column comparison is sign-invariant
-    # (|u_i . v_i| ~ 1): eigh's per-column sign is a convention, not math.
-    rng = np.random.default_rng(7)
-    resid = rng.standard_normal((400, 80)).astype(np.float32) * 0.1
-    basis_single = np.asarray(gae.fit_pca_basis(resid))
-    mesh = mesh_exec.make_compress_mesh(want)
-    basis_sharded = mesh_exec.fit_pca_basis_sharded(resid, mesh)
+    # psum basis: the covariance summed over shard groups (plus the
+    # per-stripe tail) equals the single-device stripe-by-stripe sum, and
+    # the bases agree.  That needs a FULL-RANK covariance (GAE blocks >>
+    # dims) — on a rank-deficient one the null-space eigenvectors are
+    # arbitrary.  Column comparison is sign-invariant (|u_i . v_i| ~ 1):
+    # eigh's per-column sign is a convention, not math.
+    comp3, hb3 = _make_comp(n_hb=200)
+    cov_single = comp3.residual_covariance(hb3, chunk_hyperblocks=4)
+    cov_mesh = comp3.residual_covariance(hb3, mesh=want, chunk_hyperblocks=4)
+    basis_single = comp3.fit_basis(hb3, chunk_hyperblocks=4)
+    basis_sharded = comp3.fit_basis(hb3, mesh=want, chunk_hyperblocks=4)
     align = np.abs(np.sum(basis_single * basis_sharded, axis=0))
+    cov_gap = float(np.abs(cov_mesh - cov_single).max()
+                    / np.abs(cov_single).max())
     check("psum_basis_consistent",
           basis_sharded.shape == basis_single.shape
-          and bool(np.all(align > 1 - 1e-3)),
-          f"min |col alignment| = {float(align.min()):.6f}")
+          and cov_gap < 1e-5 and bool(np.all(align > 1 - 1e-3)),
+          f"covariance gap {cov_gap:.2e}, min |col alignment| = "
+          f"{float(align.min()):.6f}")
 
     # ...and the end-to-end property that actually matters: a basis fitted
     # THROUGH the mesh still drives a guarantee-satisfying compress
     comp2, hb2 = _make_comp()
     comp2.basis = None
-    comp2.fit_basis(hb2, mesh=want)
+    comp2.fit_basis(hb2, mesh=want, chunk_hyperblocks=4)
     a2 = comp2.compress(hb2, options=base_opts)
     r2 = comp2.decompress(a2)
     d_gae = comp2.cfg.gae_block_elems or comp2.cfg.block_elems

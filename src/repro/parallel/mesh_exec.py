@@ -25,11 +25,10 @@ axis.  This module owns the three mesh-level concerns:
   its own shard produced — nothing ever crosses a shard boundary on the
   host side.
 
-The PCA basis fit also scales over the same axis: ``fit_pca_basis_sharded``
-wires ``core/gae.py``'s existing ``fit_pca_basis(axis_name=...)`` psum path
-through a ``shard_map`` trace — each shard computes its local D x D residual
-covariance, one ``psum`` makes it global (zero-padded rows contribute exactly
-nothing to ``r.T @ r``, so padding to an even shard split is exact).
+The PCA basis fit runs over the same groups
+(``HierarchicalCompressor.residual_covariance``): each shard adds its
+stripe's D x D residual covariance, one ``psum`` per group makes it global,
+and the groups' sums are added up (``replicated`` places the running sum).
 """
 from __future__ import annotations
 
@@ -37,7 +36,7 @@ from typing import Optional, Sequence
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.errors import ConfigError
 from repro.core.options import MESH_AXIS
@@ -145,37 +144,6 @@ def group_slice(group: Sequence[Span]) -> tuple[int, int]:
     return start, stop
 
 
-# ---------------------------------------------------------------------------
-# sharded PCA basis fit (psum covariance)
-# ---------------------------------------------------------------------------
-
-def fit_pca_basis_sharded(residuals: np.ndarray, mesh: Mesh) -> np.ndarray:
-    """Global-exact PCA basis of ``(N, D)`` residuals over the mesh.
-
-    Each shard computes its local ``r.T @ r`` covariance; ``core.gae``'s
-    existing ``fit_pca_basis(axis_name=...)`` psums the D x D matrix across
-    the ``hb`` axis, so communication is O(D^2) independent of N.  Rows are
-    zero-padded to an even shard split — zero rows add exactly nothing to
-    the covariance, so the result is the psum of the true per-shard
-    covariances.  Every shard then runs the same ``eigh`` on the same global
-    covariance, so the replicated basis is consistent by construction.
-    """
-    from repro.core import exec as exec_mod
-    from repro.core import gae
-
-    n_shards = mesh_shards(mesh)
-    r = np.asarray(residuals, np.float32)
-    n, d = r.shape
-    pad = (-n) % n_shards
-    if pad:
-        r = np.concatenate([r, np.zeros((pad, d), np.float32)], axis=0)
-
-    def local_fit(rr):
-        return gae.fit_pca_basis(rr, axis_name=MESH_AXIS)
-
-    fn = jax.shard_map(local_fit, mesh=mesh, in_specs=(P(MESH_AXIS),),
-                       out_specs=P(), check_vma=False)
-    fit = exec_mod.cache().get("fit_pca_basis_sharded", fn, mesh=mesh)
-    with exec_mod.stage("fit_basis_sharded", r.size):
-        return np.asarray(jax.device_get(
-            fit(exec_mod.put_sharded(r, mesh))))
+def replicated(mesh: Mesh):
+    """The sharding of an array held whole on every device of ``mesh``."""
+    return NamedSharding(mesh, P())

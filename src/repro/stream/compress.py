@@ -163,7 +163,8 @@ def stream_compress(comp: HierarchicalCompressor, hyperblocks: np.ndarray,
 
     cfg = comp.cfg
     n = hyperblocks.shape[0]
-    gae_dim = comp.prepare_compress(hyperblocks, tau, mesh=mesh)
+    gae_dim = comp.prepare_compress(hyperblocks, tau, mesh=mesh,
+                                    chunk_hyperblocks=opts.chunk_hyperblocks)
     spans = comp.stripe_spans(n, opts.chunk_hyperblocks,
                               with_gae=tau is not None)
     width = comp._chunk_width(opts.chunk_hyperblocks,

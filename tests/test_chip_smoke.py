@@ -22,15 +22,22 @@ def chip_smoke():
     return mod
 
 
-def test_single_chip_work_at_quick_size(chip_smoke, tmp_path):
-    facts = chip_smoke.run_single_chip(quick=True, out_dir=tmp_path)
-    assert facts["shape"] == (36, 5, 1536)        # E3SM geometry, k=5
-    assert facts["max_l2"] <= chip_smoke.TAU * (1 + 1e-5)
+@pytest.mark.parametrize("dataset, shape", [
+    ("e3sm", (36, 5, 1536)),                      # E3SM geometry, k=5
+    ("s3d", (144, 10, 4640)),                     # S3D geometry, k=10
+])
+def test_single_chip_work_at_quick_size(chip_smoke, tmp_path, dataset,
+                                        shape):
+    tau = chip_smoke.TAUS[dataset]
+    facts = chip_smoke.run_single_chip(dataset, quick=True, tau=tau,
+                                       out_dir=tmp_path)
+    assert facts["shape"] == shape
+    assert facts["max_l2"] <= tau * (1 + 1e-5)
     assert 0 < facts["coded_share"] <= 1 and facts["ratio"] > 1
     assert not list(tmp_path.iterdir())           # the .rba is cleaned up
 
 
-@pytest.mark.parametrize("argv", [[], ["--mesh", "4"]])
+@pytest.mark.parametrize("argv", [[], ["--mesh", "4"], ["--dataset", "s3d"]])
 def test_main_refuses_a_backend_without_tpu(chip_smoke, capsys, argv):
     assert chip_smoke.main(argv) != 0
     out, err = capsys.readouterr()

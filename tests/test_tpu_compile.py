@@ -29,6 +29,9 @@ V5E_HBM_BYTES = 16 * 10**9
 STRIPE = 64
 #: E3SM at the paper's size: 720x240x1440 values as (6,16,16) blocks, k=5
 E3SM_HYPERBLOCKS = 32400
+#: hyper-blocks of each dataset at the paper's size: S3D 58x50x640x640 as
+#: (58,5,4,4) blocks, k=10; XGC 8 planes of 16395 nodes, k=8
+PAPER_HYPERBLOCKS = {"s3d": 25600, "e3sm": E3SM_HYPERBLOCKS, "xgc": 16395}
 
 
 @pytest.fixture(scope="module")
@@ -110,13 +113,6 @@ def _stage_args(chip, dataset: str, n: int) -> dict:
                            _spec(chip, (n, cfg.hb_latent), jnp.int32),
                            [_spec(chip, (n * cfg.k, cfg.bae_latent),
                                   jnp.int32)], scalar, scalar),
-        "hbae_apply": (hbae_mod.hbae_apply, hb,
-                       _spec(chip, (n, cfg.k, cfg.block_elems))),
-        "bae_apply": (bae_mod.bae_apply, bae[0],
-                      _spec(chip, (n * cfg.k, cfg.block_elems))),
-        "fit_pca_basis": (gae.fit_pca_basis, _spec(
-            chip, (n * cfg.k * cfg.block_elems // cfg.gae_block_elems,
-                   cfg.gae_block_elems))),
     }
 
 
@@ -127,13 +123,50 @@ def test_stage_program_compiles_at_stripe_size(one_chip, dataset, program):
     _compile(fn, *args)
 
 
-@pytest.mark.parametrize("program", ["encode_frontend", "decode_backend",
-                                     "hbae_apply", "bae_apply",
-                                     "fit_pca_basis"])
-def test_e3sm_paper_size_whole_array_program_fits_one_chip(one_chip,
-                                                           program):
-    # fit and fit_basis push the whole array through one program
-    fn, *args = _stage_args(one_chip, "e3sm", E3SM_HYPERBLOCKS)[program]
+def _fit_args(chip, dataset: str) -> dict:
+    """The programs ``fit`` and ``fit_basis`` run on the paper-size field:
+    the training steps, each gathering its batch from the whole field (or
+    the whole BAE residual) on the device, and the per-stripe forward,
+    covariance (the ``eigh`` of the sum holds D_gae^2 floats)."""
+    from repro.core import training
+    from repro.train import optim as optim_mod
+
+    cfg, hb, bae = _params(chip, dataset)
+    n = PAPER_HYPERBLOCKS[dataset]
+    k, d = cfg.k, cfg.block_elems
+    opt = optim_mod.adam(lr=cfg.lr)
+    state = lambda p: jax.tree.map(                 # noqa: E731
+        lambda s: _spec(chip, s.shape, s.dtype), jax.eval_shape(opt.init, p))
+    d_gae = cfg.gae_block_elems
+    idx = lambda b: _spec(chip, (b,), jnp.int32)    # noqa: E731
+    # the field as the training loops hold it: a row per block, padded to
+    # whole 128-lane tiles
+    rows = _spec(chip, (n * k, d + -d % training.LANES))
+    return {
+        "hbae_step": (
+            lambda p, s, data, i: training._hbae_step(
+                p, s, training._batch(data, i, (k, d)), opt),
+            hb, state(hb), rows, idx(cfg.batch)),
+        "bae_step": (
+            lambda p, s, data, i: training._bae_step(
+                p, s, training._batch(data, i, (d,)), opt),
+            bae[0], state(bae[0]), rows, idx(max(4 * cfg.batch, 256))),
+        "hbae_apply": (hbae_mod.hbae_apply, hb,
+                       _spec(chip, (STRIPE, cfg.k, cfg.block_elems))),
+        "residual_covariance": (
+            exec_mod._add_residual_covariance, _spec(chip, (d_gae, d_gae)),
+            _spec(chip, (STRIPE, cfg.k, cfg.block_elems)),
+            _spec(chip, (STRIPE, cfg.k, cfg.block_elems))),
+    }
+
+
+@pytest.mark.parametrize("program", ["hbae_step", "bae_step", "hbae_apply",
+                                     "residual_covariance"])
+@pytest.mark.parametrize("dataset", ["s3d", "e3sm", "xgc"])
+def test_paper_size_fit_program_fits_one_chip(one_chip, dataset, program):
+    # fit and fit_basis run stripe by stripe; only the training steps see
+    # the whole field, which stays on the device for their gathers
+    fn, *args = _fit_args(one_chip, dataset)[program]
     _compile(fn, *args)
 
 
@@ -171,7 +204,7 @@ def test_quantize_kernel_compiles(one_chip):
 @pytest.mark.parametrize("b,n,d,heads", [
     (STRIPE, 5, 128, 1),            # E3SM HBAE: k=5 embeddings of 128
     (STRIPE, 10, 128, 1),           # S3D HBAE: k=10
-    (E3SM_HYPERBLOCKS, 5, 128, 1),  # whole-array forward of the fit
+    (E3SM_HYPERBLOCKS, 5, 128, 1),  # a whole field's embeddings at once
     (STRIPE, 10, 128, 2),           # heads folded into the batch axis
 ])
 def test_block_attention_kernel_compiles(one_chip, b, n, d, heads):
