@@ -29,6 +29,8 @@ jax's compilation cache and retraces + recompiles the model on every
    coders: archive chunks are independently codable by design (see
    docs/ARCHIVE_FORMAT.md), and the Huffman/index-set work is numpy/zlib
    dominated (GIL-releasing), so a thread pool scales the host-side loops.
+   Numpy's BLAS runs on one thread (``single_blas_thread``): the pool is the
+   parallelism.
 
 4. **Spans and counters** (``stage`` / ``stage_stats`` / ``counter_*``):
    each hot-path stage records its calls, wall and on-CPU seconds and the
@@ -42,6 +44,7 @@ from __future__ import annotations
 import contextlib
 import ctypes
 import dataclasses
+import itertools
 import os
 import re
 import sys
@@ -365,10 +368,87 @@ def _name_os_thread() -> None:
     prctl(_PR_SET_NAME, name, 0, 0, 0)
 
 
+# -- one BLAS thread per process ----------------------------------------------
+# The codec workers each run the GAE correction product (``gae._apply_codes``,
+# a float32 numpy matmul) at once.  OpenBLAS keeps ONE thread pool per
+# process, so concurrent calls queue on it, and its sgemm rounds differently
+# at different thread counts.  The pool is the parallelism: every product
+# runs on one BLAS thread, so its floats depend on neither the worker count
+# nor the host's core count (OpenBLAS still picks its kernels by CPU model).
+# The limit is process-wide, so it is set once and never restored.
+
+#: readers of the BLAS thread count in force, one per library limited;
+#: ``None`` until ``single_blas_thread`` has run
+_BLAS_READERS: Optional[list] = None
+_BLAS_LOCK = threading.Lock()
+
+
+def single_blas_thread() -> None:
+    """Limit numpy's BLAS to one thread, once per process (idempotent and
+    thread-safe).  Uses ``threadpoolctl`` where it imports, else the
+    ``openblas_set_num_threads`` of each loaded OpenBLAS; where neither finds
+    a library it does nothing, and ``blas_threads()`` reads 0."""
+    global _BLAS_READERS
+    if _BLAS_READERS is not None:
+        return
+    with _BLAS_LOCK:
+        if _BLAS_READERS is None:
+            _BLAS_READERS = _limit_blas()
+
+
+def blas_threads() -> int:
+    """The BLAS thread count in force (the largest over the libraries
+    limited); 0 where no library was found.  A few microseconds."""
+    return max((int(get()) for get in _BLAS_READERS or ()), default=0)
+
+
+def _limit_blas() -> list:
+    try:
+        from threadpoolctl import ThreadpoolController
+    except ImportError:
+        return _limit_openblas()
+    blas = ThreadpoolController().select(user_api="blas")
+    blas.limit(limits=1)
+    return [lib.get_num_threads for lib in blas.lib_controllers]
+
+
+def _limit_openblas() -> list:
+    """Without ``threadpoolctl``: every OpenBLAS mapped into the process
+    (numpy's among them), through its own exported setter.  Linux only."""
+    try:
+        with open("/proc/self/maps") as f:
+            paths = {parts[5].strip() for parts in
+                     (line.split(None, 5) for line in f) if len(parts) == 6}
+    except OSError:
+        return []
+    readers = []
+    for path in sorted(p for p in paths if "openblas" in os.path.basename(p)):
+        try:
+            lib = ctypes.CDLL(path)
+        except OSError:
+            continue
+        # plain, 64-bit-integer and scipy-openblas builds mangle the names
+        for prefix, suffix in itertools.product(("", "scipy_"),
+                                                ("", "64_", "_64")):
+            setter = getattr(lib, f"{prefix}openblas_set_num_threads{suffix}",
+                             None)
+            getter = getattr(lib, f"{prefix}openblas_get_num_threads{suffix}",
+                             None)
+            if setter is not None and getter is not None:
+                setter.argtypes, setter.restype = (ctypes.c_int,), None
+                getter.argtypes, getter.restype = (), ctypes.c_int
+                setter(1)
+                readers.append(getter)
+                break
+    return readers
+
+
 def _pool() -> ThreadPoolExecutor:
     global _POOL
     with _POOL_LOCK:
         if _POOL is None:
+            # before any worker can be inside a product
+            single_blas_thread()
             _POOL = ThreadPoolExecutor(max_workers=codec_workers(),
                                        thread_name_prefix="repro-codec",
                                        initializer=_name_os_thread)

@@ -341,7 +341,17 @@ def _apply_codes(x_r: np.ndarray, u: np.ndarray, ms: np.ndarray,
                  bin_size: float) -> np.ndarray:
     """x^R + U c for flat codes: block i owns the next ``ms[i]`` entries of
     ``cols``/``qs``.  The one place the GAE correction is computed, so the
-    encoder verifies exactly the floats the decoder will produce."""
+    encoder verifies exactly the floats the decoder will produce.
+
+    The product runs on one BLAS thread (``exec.single_blas_thread``), so
+    no worker count or core count changes its rounding; the counters
+    ``gae.apply_calls`` and ``gae.apply_blas_threads`` (the thread count in
+    force, summed) are equal while that holds."""
+    from repro.core import exec as exec_mod
+
+    exec_mod.single_blas_thread()
+    exec_mod.counter_add("gae.apply_calls")
+    exec_mod.counter_add("gae.apply_blas_threads", exec_mod.blas_threads())
     out = np.asarray(x_r, np.float32).copy()
     if not ms.sum():
         return out
