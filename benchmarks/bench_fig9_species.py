@@ -12,13 +12,14 @@ from __future__ import annotations
 import numpy as np
 
 from benchmarks.common import emit, fitted_compressor
+from repro.core.options import CompressOptions
 from repro.data.blocks import nrmse
 
 
 def main(full: bool = False) -> None:
     comp, hb = fitted_compressor("s3d")
     tau = 0.5
-    archive = comp.compress(hb, tau=tau)
+    archive = comp.compress(hb, options=CompressOptions(tau=tau))
     recon = comp.decompress(archive)
 
     # hyper-blocks are (N, k, 58*5*4*4); species axis is the leading block dim

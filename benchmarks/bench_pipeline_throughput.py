@@ -32,6 +32,7 @@ from repro.core import bae as bae_mod
 from repro.core import entropy, gae
 from repro.core import exec as exec_mod
 from repro.core import hbae as hbae_mod
+from repro.core.options import CompressOptions
 from repro.core.pipeline import Archive, ArchiveChunk, HierarchicalCompressor
 from repro.core.quantization import dequantize, quantize
 from repro.data import blocks as blocks_mod
@@ -323,10 +324,11 @@ def main(argv=None) -> int:
 
     # -- current path: warmup, then assert zero retraces across repeats -----
     exec_mod.reset_stage_stats()
-    archive = comp.compress(hb, tau=args.tau)
+    opts = CompressOptions(tau=args.tau)
+    archive = comp.compress(hb, options=opts)
     recon = comp.decompress(archive)
     traces_warm = exec_mod.total_retraces()
-    cur_comp = timed(lambda: comp.compress(hb, tau=args.tau), args.repeats)
+    cur_comp = timed(lambda: comp.compress(hb, options=opts), args.repeats)
     cur_dec = timed(lambda: comp.decompress(archive), args.repeats)
     retrace_delta = exec_mod.total_retraces() - traces_warm
 

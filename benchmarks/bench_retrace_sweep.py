@@ -29,6 +29,7 @@ import numpy as np
 from repro.core import bae as bae_mod
 from repro.core import exec as exec_mod
 from repro.core import hbae as hbae_mod
+from repro.core.options import CompressOptions
 from repro.core.pipeline import CompressorConfig, HierarchicalCompressor
 from repro.stream import stream_compress
 
@@ -76,15 +77,14 @@ def main(argv=None) -> int:
             distinct.add((stages, width))
     expected = 2 * len(distinct)        # encode_frontend + decode_backend
 
+    opts = CompressOptions(chunk_hyperblocks=args.chunk_hyperblocks)
     base = exec_mod.total_retraces()
     for n_hb, stages in combos:
         comp = make_compressor(stages, rng.integers(1 << 30))
         x = rng.normal(size=(n_hb, 2, 40)).astype(np.float32)
-        comp.compress(x, tau=None,
-                      chunk_hyperblocks=args.chunk_hyperblocks)
+        comp.compress(x, options=opts)
         batch_traces = exec_mod.total_retraces()
-        stream_compress(comp, x, tau=None,
-                        chunk_hyperblocks=args.chunk_hyperblocks)
+        stream_compress(comp, x, options=opts)
         stream_delta = exec_mod.total_retraces() - batch_traces
         if stream_delta:
             print(f"FAIL: streaming compress added {stream_delta} traces on "

@@ -41,6 +41,7 @@ import time
 import numpy as np
 
 from repro.core import exec as exec_mod
+from repro.core.options import CompressOptions
 from repro.core.pipeline import HierarchicalCompressor
 from repro.runtime import archive_io
 from repro.stream import stream_compress
@@ -79,17 +80,17 @@ def main(argv=None) -> int:
     batch_path = os.path.join(tmpdir, "batch.rba")
     stream_path = os.path.join(tmpdir, "stream.rba")
 
+    opts = CompressOptions(tau=args.tau,
+                           chunk_hyperblocks=args.chunk_hyperblocks,
+                           queue_depth=args.queue_depth)
+
     def batch_to_disk():
-        archive = comp.compress(hb, tau=args.tau,
-                                chunk_hyperblocks=args.chunk_hyperblocks)
+        archive = comp.compress(hb, options=opts)
         archive_io.write_archive(archive, batch_path)
         return archive
 
     def stream_to_disk():
-        return stream_compress(comp, hb, tau=args.tau,
-                               chunk_hyperblocks=args.chunk_hyperblocks,
-                               out_path=stream_path,
-                               queue_depth=args.queue_depth)
+        return stream_compress(comp, hb, options=opts, out_path=stream_path)
 
     # warmup both paths (jit traces, pools, page cache) before timing
     batch_archive = batch_to_disk()

@@ -12,6 +12,7 @@ import numpy as np
 
 from repro.data import synthetic
 from repro.data.blocks import nrmse
+from repro.core.options import CompressOptions
 from repro.core.pipeline import HierarchicalCompressor
 
 TAU = 0.5          # per-block l2 bound in the normalized domain
@@ -26,7 +27,7 @@ print(f"data: {hyperblocks.shape[0]} hyper-blocks of "
 comp = HierarchicalCompressor(cfg).fit(hyperblocks, seed=0)
 
 # 3. compress with the error-bound guarantee
-archive = comp.compress(hyperblocks, tau=TAU)
+archive = comp.compress(hyperblocks, options=CompressOptions(tau=TAU))
 print(f"compression ratio: {archive.compression_ratio():.1f}x "
       f"({archive.compressed_bytes():,} bytes for "
       f"{hyperblocks.nbytes:,} raw)")

@@ -5,12 +5,13 @@ GAE projects each block residual onto a PCA basis U (fit on the residuals of
 the whole dataset), keeps the top-M *quantized* coefficients per block with M
 minimal such that ||x - x^G||_2 <= tau, and corrects x^G = x^R + U_s c_q.
 
-Two implementations, proven equivalent by tests:
+One selection, checked against an oracle by tests:
 
 * ``gae_reference_loop`` — a literal per-block port of the paper's Algorithm 1
   (serial ``while delta > tau: M += 1`` loop).  The oracle.
-* ``gae_select`` — the TPU-native adaptation: because U is orthonormal, the
-  post-correction error decomposes exactly in coefficient space as
+* ``gae_select`` — the selection the encoder runs, jitted, on every
+  backend.  Because U is orthonormal, the post-correction error decomposes
+  exactly in coefficient space as
 
       err^2(M) = sum_{k>M} c_(k)^2  +  sum_{k<=M} (c_(k) - q(c_(k)))^2
 
@@ -126,48 +127,6 @@ def gae_select(residuals: Array, basis: Array, tau: float, bin_size: float,
                         err=err, ok=ok)
 
 
-def gae_apply(x: Array, x_r: Array, basis: Array, tau: float, bin_size: float,
-              *, use_kernel: bool = False) -> tuple[Array, GAESelection]:
-    """Corrected reconstruction x^G (paper Eq. 10) for a batch of blocks."""
-    sel = gae_select(x - x_r, basis, tau, bin_size, use_kernel=use_kernel)
-    return x_r + sel.corrected, sel
-
-
-def select_host(residuals: np.ndarray, basis: np.ndarray, tau: float,
-                bin_size: float) -> GAESelection:
-    """Numpy twin of ``gae_select`` for the host-side encoder on the CPU
-    backend, where XLA's row sorts run far slower than numpy's.  Same math,
-    same rounding (round-half-to-even, float32 dequantize), same fields —
-    equivalence is pinned by tests against ``gae_select``."""
-    r = np.asarray(residuals, np.float32)
-    u = np.asarray(basis, np.float32)
-    d = r.shape[-1]
-    c = r @ u
-    c2 = np.square(c)
-    order = np.argsort(-c2, axis=-1)
-    c_sorted = np.take_along_axis(c, order, axis=-1)
-    c2_sorted = np.take_along_axis(c2, order, axis=-1)
-    q_sorted = np.round(c_sorted / bin_size).astype(np.int32)
-    deq = q_sorted.astype(np.float32) * np.float32(bin_size)
-    qerr2 = np.square(c_sorted - deq)
-    total = c2_sorted.sum(axis=-1, keepdims=True)
-    tail2 = total - np.cumsum(c2_sorted, axis=-1)
-    kept2 = np.cumsum(qerr2, axis=-1)
-    err2 = np.concatenate([total, tail2 + kept2], axis=-1)
-    ok_any = err2 <= tau * tau
-    m = np.argmax(ok_any, axis=-1)
-    ok = ok_any.any(axis=-1)
-    m = np.where(ok, m, d)
-    keep = np.arange(d)[None, :] < m[:, None]
-    c_hat = np.zeros_like(deq)
-    np.put_along_axis(c_hat, order, np.where(keep, deq, np.float32(0.0)),
-                      axis=-1)
-    corrected = c_hat @ u.T
-    err = np.linalg.norm(r - corrected, axis=-1)
-    return GAESelection(m=m, order=order, q_sorted=q_sorted,
-                        corrected=corrected, err=err, ok=ok)
-
-
 # ---------------------------------------------------------------------------
 # literal Algorithm 1 (oracle; host-side, per block)
 # ---------------------------------------------------------------------------
@@ -249,15 +208,10 @@ def gae_encode_blocks(x: np.ndarray, x_r: np.ndarray, basis: np.ndarray,
     n, d = x.shape
 
     with exec_mod.stage("gae_select", x.size):
-        if jax.default_backend() == "cpu":
-            # host twin: numpy row sorts beat XLA CPU's by a wide margin, and
-            # the encoder is host-side anyway (see select_host)
-            sel = select_host(x - x_r, u, tau, bin_size)
-        else:
-            select = exec_mod.cache().get("gae_select", gae_select,
-                                          static_argnames=("use_kernel",))
-            sel = select(exec_mod.to_device(x - x_r), exec_mod.to_device(u),
-                         tau, bin_size)
+        select = exec_mod.cache().get("gae_select", gae_select,
+                                      static_argnames=("use_kernel",))
+        sel = select(exec_mod.to_device(x - x_r), exec_mod.to_device(u),
+                     tau, bin_size)
         # only the codes leave the device: the encoder never trusts the
         # selection's own ``corrected``/``err``
         m_sel, order_sel, q_sorted = exec_mod.to_host(

@@ -1,18 +1,9 @@
-"""Unified compress configuration surface: ``CompressOptions``.
+"""The one compress configuration object: ``CompressOptions``.
 
-After the streaming (PR 3) and fault-tolerance (PR 4) layers landed, the
-compress entry points had grown three divergent configuration surfaces:
-
-* ``HierarchicalCompressor.compress(hyperblocks, tau=..., chunk_hyperblocks=...)``
-* ``stream_compress(comp, hb, tau=..., chunk_hyperblocks=..., queue_depth=...,
-  fault_tolerance=FaultTolerance(...), chaos=ChaosInjector(...))``
-* ``launch/compress.py --tau/--chunk-hyperblocks/--stream/--queue-depth/
-  --retries/--stage-deadline/--chaos`` argv flags
-
-each spelling the same knobs differently.  ``CompressOptions`` is the single
-frozen configuration object all three accept; the old kwarg surfaces remain
-as thin shims that emit ``DeprecationWarning`` and delegate (see
-``HierarchicalCompressor.compress`` / ``stream_compress``).
+``HierarchicalCompressor.compress``, ``stream_compress`` and
+``launch/compress.py`` (which builds one from its argv flags) all take a
+single frozen ``CompressOptions``; there is no other way to configure a
+compress.
 
 Validation happens at CONSTRUCTION time and raises a typed
 :class:`~repro.core.errors.ConfigError` — a zero-width chunk or a mesh
@@ -32,7 +23,6 @@ import-light (no jax device initialization at option-construction time):
 from __future__ import annotations
 
 import dataclasses
-import warnings
 from typing import Optional
 
 from repro.core.errors import ConfigError
@@ -47,7 +37,7 @@ MESH_AXIS = "hb"
 class CompressOptions:
     """One frozen configuration object for a compress run (batch or stream).
 
-    Fields mirror the union of the three legacy surfaces:
+    Fields:
 
     * ``tau`` — per-GAE-block l2 error bound; ``None`` disables the GAE
       guarantee stage entirely.
@@ -55,7 +45,6 @@ class CompressOptions:
       independently-decodable archive chunk).  The pipeline may round it UP
       for GAE block alignment; it is never silently clamped up from zero —
       a non-positive width is a :class:`ConfigError` here.
-    * ``stream`` — route through the pipelined ``repro.stream`` path.
     * ``queue_depth`` — streaming inter-stage queue bound (backpressure).
     * ``retries`` — per-item transient-failure retries (enables the
       fault-tolerance ladder + quarantine fallback when set).
@@ -67,7 +56,6 @@ class CompressOptions:
     """
     tau: Optional[float] = None
     chunk_hyperblocks: int = 64
-    stream: bool = False
     queue_depth: int = 2
     retries: Optional[int] = None
     stage_deadline_s: Optional[float] = None
@@ -148,31 +136,3 @@ class CompressOptions:
     def replace(self, **changes) -> "CompressOptions":
         """Functional update (re-validates)."""
         return dataclasses.replace(self, **changes)
-
-
-def resolve_options(options: Optional[CompressOptions],
-                    legacy: dict, *, caller: str,
-                    defaults: Optional[CompressOptions] = None
-                    ) -> CompressOptions:
-    """Back-compat shim used by the compress entry points.
-
-    ``legacy`` maps CompressOptions field names to values the caller received
-    through its old kwarg surface (entries whose value is ``None``/unset are
-    dropped by the caller before passing them here).  Passing BOTH an options
-    object and legacy kwargs is an error; legacy kwargs alone emit one
-    ``DeprecationWarning`` and are folded into a fresh options object.
-    """
-    if options is not None:
-        if legacy:
-            raise ConfigError(
-                f"{caller}: pass either a CompressOptions object or legacy "
-                f"kwargs {sorted(legacy)}, not both")
-        return options
-    base = defaults if defaults is not None else CompressOptions()
-    if legacy:
-        warnings.warn(
-            f"{caller}: the {sorted(legacy)} kwarg surface is deprecated; "
-            f"pass a repro.core.options.CompressOptions instead",
-            DeprecationWarning, stacklevel=3)
-        return dataclasses.replace(base, **legacy)
-    return base

@@ -32,7 +32,7 @@ from repro.core import training
 from repro.core.errors import (ArchiveError, ChecksumMismatch, ChunkDamage,
                                ConfigError, DamageReport,
                                GuaranteeUnsatisfiable, MalformedStream)
-from repro.core.options import CompressOptions, resolve_options
+from repro.core.options import CompressOptions
 
 Array = jax.Array
 
@@ -40,10 +40,6 @@ Array = jax.Array
 #: told otherwise: compress's own default, so the basis is fitted on the
 #: stripes compress codes.
 STRIPE_HYPERBLOCKS = CompressOptions.chunk_hyperblocks
-
-#: Sentinel distinguishing "kwarg not passed" from an explicit ``None`` on
-#: the deprecated ``compress(tau=..., chunk_hyperblocks=...)`` surface.
-_UNSET = object()
 
 
 @dataclasses.dataclass
@@ -478,8 +474,7 @@ class HierarchicalCompressor:
                         g_recon[lo:lo + w]))
         return out
 
-    def compress(self, hyperblocks: np.ndarray, tau=_UNSET,
-                 chunk_hyperblocks=_UNSET,
+    def compress(self, hyperblocks: np.ndarray,
                  options: Optional[CompressOptions] = None) -> Archive:
         """Batch-synchronous compress: the device front-end runs stripe by
         stripe to completion, THEN the host GAE/entropy coders fan out over
@@ -489,20 +484,13 @@ class HierarchicalCompressor:
         chunks.
 
         Configuration comes in as ONE ``repro.core.options.CompressOptions``
-        (``options=...``); the old ``tau=``/``chunk_hyperblocks=`` kwargs
-        remain as a deprecated shim.  With ``options.mesh`` set, aligned runs
-        of stripes execute as single ``shard_map`` calls — one stripe per
-        shard — and the archive stays byte-identical to the single-device
-        result (per-shard shapes equal per-stripe shapes, so the floats are
-        bit-equal, and chunk boundaries never move).
+        (``None`` means ``CompressOptions()``).  With ``options.mesh`` set,
+        aligned runs of stripes execute as single ``shard_map`` calls — one
+        stripe per shard — and the archive stays byte-identical to the
+        single-device result (per-shard shapes equal per-stripe shapes, so
+        the floats are bit-equal, and chunk boundaries never move).
         """
-        legacy = {}
-        if tau is not _UNSET:
-            legacy["tau"] = tau
-        if chunk_hyperblocks is not _UNSET:
-            legacy["chunk_hyperblocks"] = chunk_hyperblocks
-        opts = resolve_options(options, legacy,
-                               caller="HierarchicalCompressor.compress")
+        opts = options if options is not None else CompressOptions()
         tau = opts.tau
         n, k, d = hyperblocks.shape
         mesh = None

@@ -92,7 +92,7 @@ def main(argv=None) -> int:
         # combination dies here as a typed ConfigError, not mid-run
         opts = CompressOptions(
             tau=args.tau, chunk_hyperblocks=args.chunk_hyperblocks,
-            stream=args.stream, queue_depth=args.queue_depth,
+            queue_depth=args.queue_depth,
             retries=args.retries, stage_deadline_s=args.stage_deadline,
             chaos_seed=args.chaos, mesh=args.mesh)
         if opts.mesh is not None:
@@ -116,7 +116,7 @@ def main(argv=None) -> int:
 
     exec_mod.reset_stage_stats()
     streamed_bytes = 0
-    if opts.stream:
+    if args.stream:
         from repro.stream import stream_compress
         try:
             # fault tolerance + chaos arm themselves from opts (retries /

@@ -20,9 +20,7 @@ basis — the same construction the unit tests use):
    float32 tolerance (the order of the sum differs);
 5. **sharded decompress** — the mesh decode back-end reproduces the
    single-device reconstruction within float32 tolerance and the tau
-   guarantee holds on every GAE block;
-6. **options shim** — the deprecated kwarg surface produces byte-identical
-   archives to the ``CompressOptions`` surface and warns exactly once.
+   guarantee holds on every GAE block.
 
 Prints one JSON report; exits nonzero if any check fails.  The smoke gate
 (``scripts/smoke.sh``) and ``tests/test_mesh_exec.py`` both run this.
@@ -184,16 +182,6 @@ def main() -> int:
           f"max block l2 {float(errs.max()):.4f} <= tau={TAU}, "
           f"max |recon diff| = "
           f"{float(np.max(np.abs(dec_sharded - dec_single))):.3g}")
-
-    import warnings
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        legacy = comp.compress(hb, tau=TAU, chunk_hyperblocks=4)
-    dep = [w for w in caught if issubclass(w.category, DeprecationWarning)]
-    check("options_shim",
-          archive_io.serialize_archive(legacy) == blob_single
-          and len(dep) == 1,
-          f"{len(dep)} DeprecationWarning(s)")
 
     ok = all(c["ok"] for c in checks)
     print(json.dumps({"ok": ok, "devices": n_dev, "shards": want,

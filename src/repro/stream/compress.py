@@ -45,15 +45,11 @@ import numpy as np
 
 from repro.core import exec as exec_mod
 from repro.core.errors import TransientStageError
-from repro.core.options import CompressOptions, resolve_options
+from repro.core.options import CompressOptions
 from repro.core.pipeline import Archive, ArchiveChunk, HierarchicalCompressor
 from repro.runtime.stream_writer import StreamingArchiveWriter
 from repro.stream.scheduler import RetryPolicy, StageGraph, StageSpec, \
     StreamScheduler, StreamStats
-
-#: Sentinel distinguishing "kwarg not passed" from an explicit ``None`` on
-#: the deprecated ``stream_compress(tau=..., ...)`` kwarg surface.
-_UNSET = object()
 
 
 @dataclasses.dataclass
@@ -94,10 +90,8 @@ class StreamResult:
 
 
 def stream_compress(comp: HierarchicalCompressor, hyperblocks: np.ndarray,
-                    tau=_UNSET, chunk_hyperblocks=_UNSET,
                     out_path: Optional[str] = None, *,
                     options: Optional[CompressOptions] = None,
-                    queue_depth=_UNSET,
                     host_workers: Optional[int] = None,
                     fsync_every: bool = False,
                     fault_tolerance: Optional[FaultTolerance] = None,
@@ -106,10 +100,9 @@ def stream_compress(comp: HierarchicalCompressor, hyperblocks: np.ndarray,
     ``comp.compress(hyperblocks, options=options)``.
 
     Configuration comes in as ONE ``repro.core.options.CompressOptions``
-    (``options=...``); the old ``tau=``/``chunk_hyperblocks=``/
-    ``queue_depth=`` kwargs remain as a deprecated shim.  ``out_path``,
-    ``host_workers`` and ``fsync_every`` are IO concerns of THIS entry point,
-    not compression semantics, so they stay plain kwargs.
+    (``None`` means ``CompressOptions()``).  ``out_path``, ``host_workers``
+    and ``fsync_every`` are IO concerns of THIS entry point, not compression
+    semantics, so they stay plain kwargs.
 
     When ``out_path`` is given, finished chunk sections stream into
     ``<out_path>.partial`` as they complete and the container is atomically
@@ -132,14 +125,7 @@ def stream_compress(comp: HierarchicalCompressor, hyperblocks: np.ndarray,
     per-shard block shapes equal per-stripe shapes, and the host entropy
     fan-out still consumes exactly one stripe per chunk (all shard-local).
     """
-    legacy = {}
-    if tau is not _UNSET:
-        legacy["tau"] = tau
-    if chunk_hyperblocks is not _UNSET:
-        legacy["chunk_hyperblocks"] = chunk_hyperblocks
-    if queue_depth is not _UNSET:
-        legacy["queue_depth"] = queue_depth
-    opts = resolve_options(options, legacy, caller="stream_compress")
+    opts = options if options is not None else CompressOptions()
     tau = opts.tau
     queue_depth = opts.queue_depth
 

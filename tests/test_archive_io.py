@@ -11,6 +11,7 @@ import pytest
 from repro.core import (ArchiveError, ChecksumMismatch, CompressorConfig,
                         HierarchicalCompressor, MalformedStream,
                         TruncatedArchive)
+from repro.core.options import CompressOptions
 from repro.runtime import archive_io, faultinject
 
 TAU = 0.3
@@ -29,7 +30,8 @@ def fitted():
                            hb_bin=0.02, bae_bin=0.02, gae_bin=0.02,
                            gae_block_elems=D_GAE)
     comp = HierarchicalCompressor(cfg).fit(hb, seed=0)
-    archive = comp.compress(hb, tau=TAU, chunk_hyperblocks=16)
+    archive = comp.compress(
+        hb, options=CompressOptions(tau=TAU, chunk_hyperblocks=16))
     return comp, hb, archive, archive_io.serialize_archive(archive)
 
 

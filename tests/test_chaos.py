@@ -21,11 +21,15 @@ from repro.core import hbae as hbae_mod
 from repro.core.errors import (ArchiveError, GuaranteeUnsatisfiable,
                                MalformedStream, StageDeadlineExceeded,
                                TransientStageError)
+from repro.core.options import CompressOptions
 from repro.runtime import archive_io, faultinject
 from repro.runtime.chaosinject import (ChaosInjector, ChaosPermanentFault,
                                        ChaosSpec, run_chaos_check)
 from repro.stream import (FaultTolerance, RetryPolicy, StageGraph, StageSpec,
                           StreamScheduler, stream_compress)
+
+#: the compress configuration every batch/stream parity test here runs
+OPTS = CompressOptions(tau=0.5, chunk_hyperblocks=7)
 
 
 @pytest.fixture(scope="module")
@@ -240,7 +244,7 @@ def test_quarantine_on_permanent_encode_failure(comp_hb, tmp_path,
                                                 monkeypatch):
     comp, hb = comp_hb
     out = str(tmp_path / "quarantine.rba")
-    batch = comp.compress(hb, tau=0.5, chunk_hyperblocks=7)
+    batch = comp.compress(hb, options=OPTS)
     real = HierarchicalCompressor.encode_stripe_host
 
     def poison(self, hb_start, *args, **kwargs):
@@ -252,7 +256,7 @@ def test_quarantine_on_permanent_encode_failure(comp_hb, tmp_path,
     ft = FaultTolerance(retry=RetryPolicy(max_retries=1,
                                           base_backoff_s=0.001,
                                           max_backoff_s=0.002))
-    result = stream_compress(comp, hb, tau=0.5, chunk_hyperblocks=7,
+    result = stream_compress(comp, hb, options=OPTS,
                              out_path=out, fault_tolerance=ft)
     monkeypatch.undo()
     assert result.quarantined == [2]
@@ -295,7 +299,7 @@ def test_quarantine_on_guarantee_unsatisfiable(comp_hb, tmp_path,
     monkeypatch.setattr(HierarchicalCompressor, "encode_stripe_host",
                         unsatisfiable)
     result = stream_compress(
-        comp, hb, tau=0.5, chunk_hyperblocks=7,
+        comp, hb, options=OPTS,
         fault_tolerance=FaultTolerance(retry=RetryPolicy(
             max_retries=2, base_backoff_s=0.001, max_backoff_s=0.002)))
     monkeypatch.undo()
@@ -318,7 +322,7 @@ def test_no_fault_tolerance_keeps_fail_fast_semantics(comp_hb, tmp_path,
 
     monkeypatch.setattr(HierarchicalCompressor, "encode_stripe_host", failing)
     with pytest.raises(RuntimeError, match="hard crash"):
-        stream_compress(comp, hb, tau=0.5, chunk_hyperblocks=7, out_path=out)
+        stream_compress(comp, hb, options=OPTS, out_path=out)
     monkeypatch.undo()
     assert not os.path.exists(out)
     assert os.path.exists(out + ".partial")
@@ -367,7 +371,7 @@ def test_stream_compress_under_transient_chaos_is_deterministic(comp_hb,
     runs = []
     for r in range(2):
         out = str(tmp_path / f"chaos{r}.rba")
-        result = stream_compress(comp, hb, tau=0.5, chunk_hyperblocks=7,
+        result = stream_compress(comp, hb, options=OPTS,
                                  out_path=out, fault_tolerance=ft,
                                  chaos=ChaosInjector(spec))
         runs.append((tuple(result.stats.retry_events),
@@ -377,7 +381,7 @@ def test_stream_compress_under_transient_chaos_is_deterministic(comp_hb,
         assert result.quarantined == []
     assert runs[0] == runs[1]
     assert runs[0][0]                            # chaos actually injected
-    batch = comp.compress(hb, tau=0.5, chunk_hyperblocks=7)
+    batch = comp.compress(hb, options=OPTS)
     with open(str(tmp_path / "chaos0.rba"), "rb") as f:
         assert f.read() == archive_io.serialize_archive(batch)
 
@@ -416,7 +420,7 @@ def test_partial_fuzz_containment(comp_hb, tmp_path, monkeypatch):
 
     monkeypatch.setattr(HierarchicalCompressor, "encode_stripe_host", failing)
     with pytest.raises(RuntimeError):
-        stream_compress(comp, hb, tau=0.5, chunk_hyperblocks=7, out_path=out)
+        stream_compress(comp, hb, options=OPTS, out_path=out)
     monkeypatch.undo()
     with open(out + ".partial", "rb") as f:
         partial = f.read()

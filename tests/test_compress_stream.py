@@ -19,11 +19,15 @@ from repro.core import bae as bae_mod
 from repro.core import exec as exec_mod
 from repro.core import hbae as hbae_mod
 from repro.core.errors import ChecksumMismatch
+from repro.core.options import CompressOptions
 from repro.runtime import archive_io, faultinject
 from repro.runtime.stream_writer import StreamingArchiveWriter, \
     WriterStateError
 from repro.stream import StageGraph, StageSpec, StreamScheduler, \
     stream_compress
+
+#: the compress configuration every batch/stream parity test here runs
+OPTS = CompressOptions(tau=0.5, chunk_hyperblocks=7)
 
 
 @pytest.fixture(scope="module")
@@ -133,9 +137,8 @@ def test_stream_matches_batch_byte_for_byte(comp_hb, tmp_path):
     comp, hb = comp_hb
     out = str(tmp_path / "stream.rba")
     exec_mod.reset_stage_stats()
-    batch = comp.compress(hb, tau=0.5, chunk_hyperblocks=7)
-    result = stream_compress(comp, hb, tau=0.5, chunk_hyperblocks=7,
-                             out_path=out)
+    batch = comp.compress(hb, options=OPTS)
+    result = stream_compress(comp, hb, options=OPTS, out_path=out)
     blob = archive_io.serialize_archive(batch)
     assert archive_io.serialize_archive(result.archive) == blob
     with open(out, "rb") as f:
@@ -156,8 +159,9 @@ def test_stream_matches_batch_byte_for_byte(comp_hb, tmp_path):
 
 def test_stream_without_gae_or_output(comp_hb):
     comp, hb = comp_hb
-    batch = comp.compress(hb, tau=None, chunk_hyperblocks=5)
-    result = stream_compress(comp, hb, tau=None, chunk_hyperblocks=5)
+    batch = comp.compress(hb, options=CompressOptions(chunk_hyperblocks=5))
+    result = stream_compress(
+        comp, hb, options=CompressOptions(chunk_hyperblocks=5))
     assert result.bytes_written == 0
     assert archive_io.serialize_archive(result.archive) == \
         archive_io.serialize_archive(batch)
@@ -169,7 +173,7 @@ def test_stream_without_gae_or_output(comp_hb):
 
 def test_writer_out_of_order_appends_finalize_identical(comp_hb, tmp_path):
     comp, hb = comp_hb
-    batch = comp.compress(hb, tau=0.5, chunk_hyperblocks=7)
+    batch = comp.compress(hb, options=OPTS)
     spans = [(c.hb_start, c.n_hyperblocks) for c in batch.chunks]
     out = str(tmp_path / "ooo.rba")
     w = StreamingArchiveWriter(out, n_hyperblocks=batch.n_hyperblocks,
@@ -188,7 +192,7 @@ def test_writer_out_of_order_appends_finalize_identical(comp_hb, tmp_path):
 
 def test_writer_protocol_errors(comp_hb, tmp_path):
     comp, hb = comp_hb
-    batch = comp.compress(hb, tau=0.5, chunk_hyperblocks=7)
+    batch = comp.compress(hb, options=OPTS)
     spans = [(c.hb_start, c.n_hyperblocks) for c in batch.chunks]
     out = str(tmp_path / "proto.rba")
     w = StreamingArchiveWriter(out, n_hyperblocks=batch.n_hyperblocks,
@@ -221,7 +225,7 @@ def test_writer_protocol_errors(comp_hb, tmp_path):
 
 def test_partial_is_salvageable_after_every_append(comp_hb, tmp_path):
     comp, hb = comp_hb
-    batch = comp.compress(hb, tau=0.5, chunk_hyperblocks=7)
+    batch = comp.compress(hb, options=OPTS)
     spans = [(c.hb_start, c.n_hyperblocks) for c in batch.chunks]
     out = str(tmp_path / "salvage.rba")
     w = StreamingArchiveWriter(out, n_hyperblocks=batch.n_hyperblocks,
@@ -250,7 +254,7 @@ def test_partial_is_salvageable_after_every_append(comp_hb, tmp_path):
 
 def test_crash_mid_stream_truncation_salvage(comp_hb, tmp_path):
     comp, hb = comp_hb
-    batch = comp.compress(hb, tau=0.5, chunk_hyperblocks=7)
+    batch = comp.compress(hb, options=OPTS)
     spans = [(c.hb_start, c.n_hyperblocks) for c in batch.chunks]
     assert spans == [(0, 7), (7, 7), (14, 7), (21, 3)]
     out = str(tmp_path / "crash.rba")
@@ -315,7 +319,7 @@ def test_stream_compress_failure_keeps_salvageable_partial(comp_hb, tmp_path,
         return real(self, hb_start, *args, **kwargs)
     monkeypatch.setattr(HierarchicalCompressor, "encode_stripe_host", failing)
     with pytest.raises(RuntimeError, match="simulated encoder crash"):
-        stream_compress(comp, hb, tau=0.5, chunk_hyperblocks=7, out_path=out)
+        stream_compress(comp, hb, options=OPTS, out_path=out)
     monkeypatch.undo()
     assert not os.path.exists(out)            # never finalized
     with open(out + ".partial", "rb") as f:
@@ -323,7 +327,7 @@ def test_stream_compress_failure_keeps_salvageable_partial(comp_hb, tmp_path,
     salvaged = archive_io.deserialize_archive(partial, strict=False)
     good = [i for i, c in enumerate(salvaged.chunks) if c is not None]
     assert good == [0, 1]                     # chunks before the crash landed
-    batch = comp.compress(hb, tau=0.5, chunk_hyperblocks=7)
+    batch = comp.compress(hb, options=OPTS)
     for i in good:                            # and are byte-exact vs batch
         assert archive_io.pack_chunk_section(salvaged.chunks[i]) == \
             archive_io.pack_chunk_section(batch.chunks[i])

@@ -125,14 +125,55 @@ def test_gae_encode_blocks_coarse_bin_fallback():
     assert any(c.bin_exp > 0 for c in codes)
 
 
+@pytest.mark.parametrize("d, tau, bin_size", [
+    (80, 0.0447, 0.01), (256, 0.2, 0.02), (1521, 0.4875, 0.05),
+], ids=["s3d", "e3sm", "xgc"])
+def test_gae_encode_blocks_matches_reference_at_cell_widths(d, tau,
+                                                            bin_size):
+    """At each benchmark cell's GAE width, tau and bin, the encoder (jitted
+    selection, then verify and repair) gives every block the repair left
+    alone the M of the paper's Algorithm 1, and decodes within tau."""
+    from repro.core import exec as exec_mod
+    rng = np.random.default_rng(d)
+    n = 12
+    basis = np.linalg.qr(rng.standard_normal((d, d)))[0].astype(np.float32)
+    # residual spectra decaying over the basis (a dozen or so coefficients
+    # per block); a quarter of the blocks are already within tau
+    c = 2 * tau * rng.standard_normal((n, d)) * np.exp(-np.arange(d) / 8)
+    c[:n // 4] *= 0.1
+    x_r = rng.standard_normal((n, d)).astype(np.float32)
+    x = (x_r + c @ basis.T).astype(np.float32)
+
+    exec_mod.reset_stage_stats()
+    _, codes = gae.gae_encode_blocks(x, x_r, basis, tau, bin_size)
+    repaired = exec_mod.counters().get("gae.repaired_blocks", 0)
+    select = exec_mod.cache().get("gae_select", gae.gae_select,
+                                  static_argnames=("use_kernel",))
+    sel_m = np.asarray(select(x - x_r, basis, tau, bin_size).m)
+    ms = np.array([code.m for code in codes])
+    # a repair round raises a block's M or its bin exponent
+    untouched = (ms == sel_m) & np.array([code.bin_exp == 0
+                                          for code in codes])
+    assert (~untouched).sum() == repaired
+    assert untouched.sum() >= n // 2
+    _, ref_ms = gae.gae_reference_loop(x, x_r, basis, tau, bin_size)
+    np.testing.assert_array_equal(ms[untouched], np.asarray(ref_ms)[untouched])
+    assert (ms == 0).any() and (ms > 0).any()
+
+    dec = gae.gae_decode_blocks(x_r, basis, codes, bin_size)
+    errs = np.linalg.norm(x - dec, axis=1)
+    assert errs.max() <= tau, errs.max()
+    exec_mod.reset_stage_stats()
+
+
 @pytest.mark.parametrize("operand_dtype", [jnp.float32, jnp.bfloat16])
 def test_gae_encode_device_branch_holds_tau_on_decode(monkeypatch,
                                                       operand_dtype):
-    """Off the CPU backend the encoder selects with the jitted ``gae_select``,
-    whose matmuls a TPU may run with bf16 operands at default precision.
-    Steer the encoder onto that branch (bf16 case: with the selection's
-    operands rounded as such a pass rounds them) and check every block the
-    decoder rebuilds from the emitted codes against tau, with no slack."""
+    """The encoder selects with the jitted ``gae_select``, whose matmuls a
+    TPU may run with bf16 operands at default precision.  Run it (bf16 case:
+    with the selection's operands rounded as such a pass rounds them) and
+    check every block the decoder rebuilds from the emitted codes against
+    tau, with no slack."""
     from repro.core import exec as exec_mod
     rng = np.random.default_rng(5)
     n, d, tau, bin_size = 2048, 256, 0.5, 0.01
@@ -150,9 +191,8 @@ def test_gae_encode_device_branch_holds_tau_on_decode(monkeypatch,
 
     monkeypatch.setattr(exec_mod, "_CACHE", exec_mod.JitCache())
     monkeypatch.setattr(gae, "gae_select", select_at_operand_precision)
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     out, codes = gae.gae_encode_blocks(x, x_r, basis, tau, bin_size)
-    assert exec_mod.retrace_counts() == {"gae_select": 1}   # device branch
+    assert exec_mod.retrace_counts() == {"gae_select": 1}   # jitted select
     dec = gae.gae_decode_blocks(x_r, basis, codes, bin_size)
     np.testing.assert_array_equal(dec, out)
     errs = np.linalg.norm(x - dec, axis=1)

@@ -15,6 +15,7 @@ from repro.core import entropy, gae
 from repro.core import exec as exec_mod
 from repro.core import hbae as hbae_mod
 from repro.core.errors import GuaranteeUnsatisfiable, MalformedStream
+from repro.core.options import CompressOptions
 from repro.runtime import archive_io
 
 
@@ -76,11 +77,11 @@ def test_jit_cache_counts_retraces_not_calls():
 def test_roundtrip_retrace_stable_after_warmup(comp_hb):
     comp, hb = comp_hb
     # warmup traces every program for this shape
-    a = comp.compress(hb, tau=0.5)
+    a = comp.compress(hb, options=CompressOptions(tau=0.5))
     comp.decompress(a)
     before = exec_mod.total_retraces()
     for _ in range(2):
-        a = comp.compress(hb, tau=0.5)
+        a = comp.compress(hb, options=CompressOptions(tau=0.5))
         comp.decompress(a)
     assert exec_mod.total_retraces() == before, exec_mod.retrace_counts()
 
@@ -90,7 +91,6 @@ def test_decompress_decodes_at_the_stripe_shapes_compress_ran(comp_hb,
     # the GAE encoder verified tau against compress's per-stripe decode; a
     # program compiled for another batch shape may round differently on an
     # accelerator, so decompress must not compile one
-    from repro.core.options import CompressOptions
     comp, hb = comp_hb
     monkeypatch.setattr(exec_mod, "_CACHE", exec_mod.JitCache())
     archive = comp.compress(hb, options=CompressOptions(
@@ -225,21 +225,6 @@ def test_gae_codes_are_ascending_index_order():
         assert np.all(np.diff(c.indices) > 0)   # strictly ascending
 
 
-def test_select_host_matches_device_select():
-    rng = np.random.default_rng(3)
-    d = 48
-    basis = np.linalg.qr(rng.standard_normal((d, d)))[0].astype(np.float32)
-    for trial in range(3):
-        r = rng.standard_normal((16, d)).astype(np.float32) * (0.2 + trial)
-        host = gae.select_host(r, basis, tau=0.3, bin_size=0.02)
-        dev = jax.device_get(gae.gae_select(
-            jax.numpy.asarray(r), jax.numpy.asarray(basis), 0.3, 0.02))
-        np.testing.assert_array_equal(host.m, dev.m)
-        np.testing.assert_array_equal(host.ok, dev.ok)
-        np.testing.assert_array_equal(host.q_sorted, dev.q_sorted)
-        np.testing.assert_allclose(host.corrected, dev.corrected, atol=1e-5)
-
-
 # ---------------------------------------------------------------------------
 # accounting bugfixes
 # ---------------------------------------------------------------------------
@@ -255,7 +240,7 @@ def test_model_bytes_uses_leaf_dtype_width():
 
 def test_compressed_bytes_matches_framing_and_caches(comp_hb):
     comp, hb = comp_hb
-    archive = comp.compress(hb, tau=0.5)
+    archive = comp.compress(hb, options=CompressOptions(tau=0.5))
     blob = archive_io.serialize_archive(archive)
     assert archive_io.serialized_size(archive) == len(blob)
     assert archive.compressed_bytes() == len(blob)
@@ -272,7 +257,8 @@ def test_compressed_bytes_matches_framing_and_caches(comp_hb):
 
 def test_nonstrict_decode_bit_identical_on_undamaged_archive(comp_hb):
     comp, hb = comp_hb
-    archive = comp.compress(hb, tau=0.5, chunk_hyperblocks=8)
+    archive = comp.compress(
+        hb, options=CompressOptions(tau=0.5, chunk_hyperblocks=8))
     strict = comp.decompress(archive)
     tolerant, report = comp.decompress(archive, strict=False)
     assert report.ok and not report.damaged

@@ -2,9 +2,9 @@
 
 Two layers:
 
-* in-process tests — option validation, the resolve_options shim, shard
-  group planning, and single-device equivalence of the options surface
-  (these run on however many devices the test process happens to have);
+* in-process tests — option validation, shard group planning, and
+  single-device equivalence of ``mesh=1`` (these run on however many
+  devices the test process happens to have);
 * the multi-device parity gate — ``repro.parallel.mesh_check`` run as a
   SUBPROCESS, because ``--xla_force_host_platform_device_count`` is frozen
   at first jax import and pytest has long since imported jax.
@@ -13,13 +13,12 @@ import json
 import os
 import subprocess
 import sys
-import warnings
 
 import numpy as np
 import pytest
 
 from repro.core.errors import ConfigError
-from repro.core.options import MESH_AXIS, CompressOptions, resolve_options
+from repro.core.options import MESH_AXIS, CompressOptions
 from repro.parallel import mesh_exec
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -94,37 +93,6 @@ def test_options_fault_tolerant_views():
     assert CompressOptions(mesh=3).mesh_shards() == 3
 
 
-# -- resolve_options shim -----------------------------------------------------
-
-def test_resolve_options_passthrough():
-    opts = CompressOptions(tau=0.5)
-    assert resolve_options(opts, {}, caller="t") is opts
-
-
-def test_resolve_options_rejects_both_surfaces():
-    with pytest.raises(ConfigError, match="not both"):
-        resolve_options(CompressOptions(), {"tau": 0.5}, caller="t")
-
-
-def test_resolve_options_legacy_warns_once():
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        opts = resolve_options(None, {"tau": 0.5, "chunk_hyperblocks": 8},
-                               caller="t")
-    dep = [w for w in caught if issubclass(w.category, DeprecationWarning)]
-    assert len(dep) == 1
-    assert "deprecated" in str(dep[0].message)
-    assert opts.tau == 0.5 and opts.chunk_hyperblocks == 8
-
-
-def test_resolve_options_no_args_no_warning():
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        opts = resolve_options(None, {}, caller="t")
-    assert not caught
-    assert opts == CompressOptions()
-
-
 # -- shard group planning -----------------------------------------------------
 
 def _spans(widths):
@@ -179,24 +147,16 @@ def test_make_compress_mesh_rejects_impossible():
 
 # -- options surface equivalence (single device) ------------------------------
 
-def test_compress_options_equals_legacy_kwargs(comp_hb):
-    from repro.runtime import archive_io
+@pytest.mark.parametrize("entry", ["compress", "stream_compress"])
+def test_compress_entry_points_take_only_options(comp_hb, entry):
+    """``CompressOptions`` is the one configuration surface: the old
+    ``tau=`` kwarg is no parameter of either entry point."""
+    from repro.stream import stream_compress
     comp, hb = comp_hb
-    via_opts = comp.compress(
-        hb, options=CompressOptions(tau=0.5, chunk_hyperblocks=8))
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        via_legacy = comp.compress(hb, tau=0.5, chunk_hyperblocks=8)
-    dep = [w for w in caught if issubclass(w.category, DeprecationWarning)]
-    assert len(dep) == 1
-    assert archive_io.serialize_archive(via_opts) == \
-        archive_io.serialize_archive(via_legacy)
-
-
-def test_compress_rejects_mixed_surfaces(comp_hb):
-    comp, hb = comp_hb
-    with pytest.raises(ConfigError, match="not both"):
-        comp.compress(hb, tau=0.5, options=CompressOptions(tau=0.5))
+    call = {"compress": lambda **kw: comp.compress(hb, **kw),
+            "stream_compress": lambda **kw: stream_compress(comp, hb, **kw)}
+    with pytest.raises(TypeError, match="tau"):
+        call[entry](tau=0.5)
 
 
 def test_mesh1_options_byte_identical_to_unsharded(comp_hb):
@@ -252,5 +212,4 @@ def test_mesh_check_subprocess_four_devices():
     assert report["devices"] >= 4
     names = {c["name"] for c in report["checks"]}
     assert {"batch_parity", "stream_parity", "zero_retraces_after_warmup",
-            "psum_basis_consistent", "sharded_decompress",
-            "options_shim"} <= names
+            "psum_basis_consistent", "sharded_decompress"} <= names

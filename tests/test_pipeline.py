@@ -3,6 +3,7 @@ import numpy as np
 import pytest
 
 from repro.core import CompressorConfig, HierarchicalCompressor
+from repro.core.options import CompressOptions
 from repro.data import blocks as blocks_mod
 from repro.data import synthetic
 
@@ -42,7 +43,7 @@ def test_hyperblock_roundtrip(s3d_small):
 
 def test_compress_decompress_roundtrip_no_gae(fitted):
     comp, hb, _, _ = fitted
-    archive = comp.compress(hb, tau=None)
+    archive = comp.compress(hb, options=CompressOptions(tau=None))
     recon = comp.decompress(archive)
     assert recon.shape == hb.shape
     assert np.isfinite(recon).all()
@@ -52,7 +53,7 @@ def test_compress_decompress_roundtrip_no_gae(fitted):
 def test_gae_guarantee_end_to_end(fitted):
     comp, hb, _, _ = fitted
     tau = 0.25
-    archive = comp.compress(hb, tau=tau)
+    archive = comp.compress(hb, options=CompressOptions(tau=tau))
     recon = comp.decompress(archive)
     d_gae = comp.cfg.gae_block_elems
     x = hb.reshape(-1, d_gae)
@@ -63,14 +64,14 @@ def test_gae_guarantee_end_to_end(fitted):
 
 def test_tighter_tau_costs_more_bytes(fitted):
     comp, hb, _, _ = fitted
-    loose = comp.compress(hb, tau=0.5).compressed_bytes()
-    tight = comp.compress(hb, tau=0.05).compressed_bytes()
-    assert tight > loose
+    loose = comp.compress(hb, options=CompressOptions(tau=0.5))
+    tight = comp.compress(hb, options=CompressOptions(tau=0.05))
+    assert tight.compressed_bytes() > loose.compressed_bytes()
 
 
 def test_archive_accounting(fitted):
     comp, hb, _, _ = fitted
-    archive = comp.compress(hb, tau=0.25)
+    archive = comp.compress(hb, options=CompressOptions(tau=0.25))
     assert archive.n_values == hb.size
     assert archive.compressed_bytes() > 0
     assert archive.compression_ratio(include_model_bytes=comp.model_bytes()) < \
@@ -82,8 +83,8 @@ def test_save_load_roundtrip(fitted, tmp_path):
     p = str(tmp_path / "comp.pkl")
     comp.save(p)
     comp2 = HierarchicalCompressor.load(p)
-    a1 = comp.compress(hb, tau=0.25)
-    a2 = comp2.compress(hb, tau=0.25)
+    a1 = comp.compress(hb, options=CompressOptions(tau=0.25))
+    a2 = comp2.compress(hb, options=CompressOptions(tau=0.25))
     np.testing.assert_allclose(comp.decompress(a1), comp2.decompress(a2),
                                atol=1e-6)
 

@@ -156,7 +156,6 @@ def test_gae_counters_and_stages(bin_size, repaired):
 def test_gae_device_branch_counts_its_transfers(monkeypatch):
     x, x_r, basis = _gae_case(2, 12, 16, 0.05)
     monkeypatch.setattr(exec_mod, "_CACHE", exec_mod.JitCache())
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     exec_mod.reset_stage_stats()
     gae.gae_encode_blocks(x, x_r, basis, tau=0.01, bin_size=0.01)
     counts = exec_mod.counters()
@@ -180,10 +179,15 @@ def test_transfer_counters_equal_the_arrays_bytes(comp_hb):
     exec_mod.reset_stage_stats()
     archive = comp.compress(hb, options=OPTS)
     counts = exec_mod.counters()
-    # up: each stripe; down: its reconstruction and latents (the GAE
-    # selection runs on the host on the CPU backend)
-    assert counts["xfer.h2d_bytes"] == hb.nbytes
-    assert counts["xfer.d2h_bytes"] == hb.nbytes + _latent_bytes(comp, 24)
+    # up: each stripe, then for the GAE selection its residual and the
+    # basis; down: its reconstruction and latents, then the selection's
+    # int32 codes, m (one per GAE block), order and q_sorted (D per block)
+    n_stripes = len(archive.chunks)
+    n_gae = hb.size // comp.cfg.gae_block_elems
+    assert counts["xfer.h2d_bytes"] == \
+        2 * hb.nbytes + n_stripes * comp.basis.nbytes
+    assert counts["xfer.d2h_bytes"] == \
+        hb.nbytes + _latent_bytes(comp, 24) + 4 * n_gae + 2 * hb.nbytes
 
     exec_mod.reset_stage_stats()
     comp.decompress(archive)
