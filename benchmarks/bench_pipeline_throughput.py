@@ -49,9 +49,11 @@ def legacy_gae_encode_blocks(x, x_r, basis, tau, bin_size, max_refine=20):
     x_r = np.asarray(x_r, np.float32)
     u = np.asarray(basis, np.float32)
     n, d = x.shape
-    sel = jax.device_get(gae.gae_select(jnp.asarray(x - x_r), jnp.asarray(u),
-                                        tau, bin_size))
-    out = x_r + np.asarray(sel.corrected)
+    r = jnp.asarray(x - x_r)
+    sel = gae.gae_select(r, jnp.asarray(u), tau, bin_size)
+    corrected, _ = gae.gae_correct(r, jnp.asarray(u), sel, bin_size)
+    sel = jax.device_get(sel)
+    out = x_r + np.asarray(corrected)
     codes = []
     for i in range(n):
         m = int(sel.m[i])

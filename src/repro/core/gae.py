@@ -19,6 +19,7 @@ One selection, checked against an oracle by tests:
   falls out of one projection (MXU matmul), one sort, two cumulative sums and
   one comparison — branch-free and batched.  This replaces the paper's serial
   re-quantize/re-reconstruct loop (GPU/CPU-style) with a one-shot form.
+  ``gae_correct`` rebuilds x^G - x^R from a selection's codes.
 
 Scale: the basis comes from the D x D residual covariance
 (``residual_covariance``), which adds up over disjoint sets of blocks, so the
@@ -79,14 +80,15 @@ class GAESelection(NamedTuple):
     m: Array            # (N,)   minimal M per block (0 = block already within tau)
     order: Array        # (N, D) basis indices sorted by coefficient magnitude desc
     q_sorted: Array     # (N, D) quantized (int) coefficients in sorted order
-    corrected: Array    # (N, D) corrected residual reconstruction  U_s c_q
-    err: Array          # (N,)   actual l2 error after correction
     ok: Array           # (N,)   bool, err <= tau achievable with this bin size
 
 
 def gae_select(residuals: Array, basis: Array, tau: float, bin_size: float,
                *, use_kernel: bool = False) -> GAESelection:
-    """Batched minimal-M selection. residuals: (N, D); basis: (D, D)."""
+    """Batched minimal-M selection. residuals: (N, D); basis: (D, D).
+
+    Returns only the codes; ``gae_correct`` rebuilds the correction from
+    them for callers that want it."""
     r = residuals.astype(jnp.float32)
     if use_kernel:
         from repro.kernels.gae_project import ops as gp_ops
@@ -95,9 +97,14 @@ def gae_select(residuals: Array, basis: Array, tau: float, bin_size: float,
         c = r @ basis                                  # (N, D) coefficients
         c2 = jnp.square(c)
 
-    order = jnp.argsort(-c2, axis=-1)                  # descending magnitude
-    c_sorted = jnp.take_along_axis(c, order, axis=-1)
-    c2_sorted = jnp.take_along_axis(c2, order, axis=-1)
+    # descending magnitude: one stable sort keyed on -c2 carries the basis
+    # indices and the coefficients along (jnp.argsort's sort, so the same
+    # ``order``), instead of gathering them back by ``order``, which a TPU
+    # lowers as one gather per element
+    iota = jax.lax.broadcasted_iota(jnp.int32, c.shape, c.ndim - 1)
+    neg_c2_sorted, order, c_sorted = jax.lax.sort(
+        (-c2, iota, c), dimension=c.ndim - 1, num_keys=1, is_stable=True)
+    c2_sorted = -neg_c2_sorted
 
     q_sorted = quantize(c_sorted, bin_size)
     deq = dequantize(q_sorted, bin_size)
@@ -112,19 +119,29 @@ def gae_select(residuals: Array, basis: Array, tau: float, bin_size: float,
     m = jnp.argmax(ok_any, axis=-1)                             # first M satisfying
     ok = jnp.any(ok_any, axis=-1)
     m = jnp.where(ok, m, residuals.shape[-1])                   # fall back to full-D
+    return GAESelection(m=m, order=order, q_sorted=q_sorted, ok=ok)
 
-    # corrected residual: U @ (masked quantized coeffs un-permuted).  The
-    # un-permute is a row-local GATHER via the inverse permutation — a row
-    # scatter (.at[].set) here makes GSPMD replicate the whole coefficient
-    # matrix across the mesh (§Perf gae_select iteration 2).
-    keep = jnp.arange(residuals.shape[-1])[None, :] < m[:, None]
-    deq_masked = jnp.where(keep, deq, 0.0)
-    inv_order = jnp.argsort(order, axis=-1)
-    c_hat = jnp.take_along_axis(deq_masked, inv_order, axis=-1)
+
+@jax.jit
+def gae_correct(residuals: Array, basis: Array, sel: GAESelection,
+                bin_size: float) -> tuple[Array, Array]:
+    """The correction a selection codes: ``corrected`` (N, D) = U_s c_q, the
+    kept dequantized coefficients un-permuted to basis order, and ``err``
+    (N,) = ||r - corrected||_2.
+
+    The un-permute is a sort keyed on ``order`` (a permutation per row)
+    that carries the coefficients, row-local like the selection's sort: no
+    per-element gather, and no row scatter (``.at[].set``), which makes
+    GSPMD replicate the whole coefficient matrix across a mesh."""
+    r = residuals.astype(jnp.float32)
+    d = residuals.shape[-1]
+    keep = jnp.arange(d)[None, :] < sel.m[:, None]
+    deq_masked = jnp.where(keep, dequantize(sel.q_sorted, bin_size), 0.0)
+    _, c_hat = jax.lax.sort((sel.order, deq_masked), dimension=r.ndim - 1,
+                            num_keys=1)
     corrected = c_hat @ basis.T
     err = jnp.linalg.norm(r - corrected, axis=-1)
-    return GAESelection(m=m, order=order, q_sorted=q_sorted, corrected=corrected,
-                        err=err, ok=ok)
+    return corrected, err
 
 
 # ---------------------------------------------------------------------------
@@ -180,10 +197,10 @@ def gae_encode_blocks(x: np.ndarray, x_r: np.ndarray, basis: np.ndarray,
     Uses the one-shot vectorized selection, then verifies each block against
     the reconstruction the DECODER will compute from the emitted codes
     (``gae_decode_blocks``' host float32 arithmetic, run here on the same
-    codes) — not against the selection's own ``corrected``, which on an
-    accelerator may come from a lower-precision matmul.  Blocks over ``tau``
-    are repaired in rounds: more coefficients first, then a halved bin (per-
-    block ``bin_exp``), each round re-decoding and re-verifying the whole
+    codes) — not against ``gae_correct``, which on an accelerator may come
+    from a lower-precision matmul.  Blocks over ``tau`` are repaired in
+    rounds: more coefficients first, then a halved bin (per-block
+    ``bin_exp``), each round re-decoding and re-verifying the whole
     batch.  With a full-rank basis the quantization error goes to 0 under
     refinement; if the budget is exhausted with ``err > tau`` (rank-deficient
     basis, ``max_refine`` too small), raises ``GuaranteeUnsatisfiable``
@@ -212,8 +229,8 @@ def gae_encode_blocks(x: np.ndarray, x_r: np.ndarray, basis: np.ndarray,
                                       static_argnames=("use_kernel",))
         sel = select(exec_mod.to_device(x - x_r), exec_mod.to_device(u),
                      tau, bin_size)
-        # only the codes leave the device: the encoder never trusts the
-        # selection's own ``corrected``/``err``
+        # only the codes leave the device: the encoder verifies with the
+        # decoder's arithmetic, never with ``gae_correct``
         m_sel, order_sel, q_sorted = exec_mod.to_host(
             (sel.m, sel.order, sel.q_sorted))
     with exec_mod.stage("gae_verify", x.size):

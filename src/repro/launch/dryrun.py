@@ -256,7 +256,9 @@ def build_compressor_cell(shape_name: str, multi_pod: bool):
             basis = vecs[:, ::-1]
             sel = gae_mod.gae_select(residuals, basis, tau=1e-2,
                                      bin_size=1e-3)
-            return sel.corrected, sel.m, sel.err
+            corrected, err = gae_mod.gae_correct(residuals, basis, sel,
+                                                 bin_size=1e-3)
+            return corrected, sel.m, err
         args = (jax.ShapeDtypeStruct((n, d), jnp.float32),)
         in_specs = (P(dp, None),)
         out_specs = (P(dp, None), P(dp), P(dp))

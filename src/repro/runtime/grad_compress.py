@@ -180,7 +180,7 @@ def gae_compress_grads(grads: PyTree, *, tau: float, bin_size: float = 1e-4,
                        block: int = 256) -> tuple[PyTree, dict]:
     """Per-block guaranteed ||g - g^G||_2 <= tau using the paper's one-shot
     selection (Algorithm 1, batched form).  Returns (bounded grads, stats)."""
-    from repro.core.gae import fit_pca_basis, gae_select
+    from repro.core.gae import fit_pca_basis, gae_correct, gae_select
 
     kept = jnp.zeros((), jnp.float32)
     total = jnp.zeros((), jnp.float32)
@@ -194,7 +194,8 @@ def gae_compress_grads(grads: PyTree, *, tau: float, bin_size: float = 1e-4,
         sel = gae_select(gb, basis, tau, bin_size)
         kept += jnp.sum(sel.m)
         total += gb.size
-        return _unblocked(sel.corrected, g.shape, g.dtype)
+        corrected, _ = gae_correct(gb, basis, sel, bin_size)
+        return _unblocked(corrected, g.shape, g.dtype)
 
     out = jax.tree.map(leaf, grads)
     return out, {"kept_coeffs": kept, "total_elems": total,

@@ -91,11 +91,93 @@ def test_pca_basis_orthonormal():
 def test_gae_select_matches_reference_loop():
     x, x_r, basis = _setup_gae()
     tau, bin_size = 0.8, 0.01
-    sel = gae.gae_select(jnp.asarray(x - x_r), jnp.asarray(basis), tau, bin_size)
+    r, u = jnp.asarray(x - x_r), jnp.asarray(basis)
+    sel = gae.gae_select(r, u, tau, bin_size)
+    corrected, _ = gae.gae_correct(r, u, sel, bin_size)
     ref_out, ref_ms = gae.gae_reference_loop(x, x_r, basis, tau, bin_size)
     np.testing.assert_array_equal(np.asarray(sel.m), np.asarray(ref_ms))
-    np.testing.assert_allclose(x_r + np.asarray(sel.corrected), ref_out,
+    np.testing.assert_allclose(x_r + np.asarray(corrected), ref_out,
                                atol=1e-4)
+
+
+def _decaying_residuals(d, tau, n=12):
+    """Orthonormal (d, d) basis and residuals whose coefficients decay over
+    it (a dozen or so per block above tau); a quarter of the blocks are
+    already within tau, and block 0 is zero, so every magnitude ties."""
+    rng = np.random.default_rng(d)
+    basis = np.linalg.qr(rng.standard_normal((d, d)))[0].astype(np.float32)
+    c = 2 * tau * rng.standard_normal((n, d)) * np.exp(-np.arange(d) / 8)
+    c[:n // 4] *= 0.1
+    c[0] = 0.0
+    return (c @ basis.T).astype(np.float32), basis
+
+
+@pytest.mark.parametrize("d, tau, bin_size", [
+    (80, 0.0447, 0.01), (256, 0.2, 0.02), (1521, 0.4875, 0.05),
+], ids=["s3d", "e3sm", "xgc"])
+def test_gae_select_codes_match_numpy_twin(d, tau, bin_size):
+    """At each cell's GAE width, tau and bin, the jitted selection's codes
+    equal, bit for bit, a numpy twin that sorts by magnitude and gathers the
+    coefficients back by the order; its program sorts once and gathers
+    nothing."""
+    r, basis = _decaying_residuals(d, tau)
+    select = jax.jit(gae.gae_select)
+    sel = select(r, basis, tau, bin_size)
+
+    c = np.asarray(jnp.asarray(r) @ jnp.asarray(basis))   # the same projection
+    c2 = np.square(c)
+    order = np.argsort(-c2, axis=1, kind="stable")
+    c_sorted = np.take_along_axis(c, order, 1)
+    c2_sorted = np.take_along_axis(c2, order, 1)
+    q_sorted = np.round(c_sorted / np.float32(bin_size)).astype(np.int32)
+    deq = q_sorted.astype(np.float32) * np.float32(bin_size)
+    total = c2_sorted.sum(axis=1, keepdims=True, dtype=np.float32)
+    err2 = np.concatenate([total, total - np.cumsum(c2_sorted, axis=1)
+                           + np.cumsum(np.square(c_sorted - deq), axis=1)],
+                          axis=1)
+    ok_any = err2 <= np.float32(tau) * np.float32(tau)
+    m = np.where(ok_any.any(axis=1), ok_any.argmax(axis=1), d)
+
+    np.testing.assert_array_equal(np.asarray(sel.order), order)
+    np.testing.assert_array_equal(np.asarray(sel.q_sorted), q_sorted)
+    np.testing.assert_array_equal(np.asarray(sel.m), m)
+    assert (m == 0).any() and ((m > 0) & (m < d)).any()
+    np.testing.assert_array_equal(order[0], np.arange(d))   # ties keep index order
+
+    text = select.lower(r, basis, tau, bin_size).as_text()
+    assert text.count("stablehlo.sort") == 1
+    assert "gather" not in text
+
+
+def test_gae_correct_matches_unpermute_and_matmul():
+    """``gae_correct``'s sort keyed on ``order`` gives, bit for bit, what
+    the inverse-permutation gather and basis product gave."""
+    rng = np.random.default_rng(7)
+    n, d, bin_size = 64, 96, 0.01
+    r = rng.standard_normal((n, d)).astype(np.float32)
+    basis = np.linalg.qr(rng.standard_normal((d, d)))[0].astype(np.float32)
+    order = np.argsort(rng.random((n, d)), axis=1).astype(np.int32)
+    sel = gae.GAESelection(
+        m=jnp.asarray(rng.integers(0, d + 1, n), jnp.int32),
+        order=jnp.asarray(order),
+        q_sorted=jnp.asarray(rng.integers(-300, 300, (n, d)), jnp.int32),
+        ok=jnp.ones(n, bool))
+
+    @jax.jit
+    def unpermute_and_matmul(r, basis, sel):
+        keep = jnp.arange(d)[None, :] < sel.m[:, None]
+        deq_masked = jnp.where(keep, sel.q_sorted.astype(jnp.float32)
+                               * bin_size, 0.0)
+        inv_order = jnp.argsort(sel.order, axis=-1)
+        c_hat = jnp.take_along_axis(deq_masked, inv_order, axis=-1)
+        corrected = c_hat @ basis.T
+        return corrected, jnp.linalg.norm(r - corrected, axis=-1)
+
+    corrected, err = gae.gae_correct(r, basis, sel, bin_size)
+    want_corrected, want_err = unpermute_and_matmul(r, basis, sel)
+    np.testing.assert_array_equal(np.asarray(corrected),
+                                  np.asarray(want_corrected))
+    np.testing.assert_array_equal(np.asarray(err), np.asarray(want_err))
 
 
 def test_gae_select_zero_m_for_small_residuals():

@@ -133,14 +133,16 @@ def test_gae_project_sweep(n, d):
 
 def test_gae_project_matches_gae_select_path():
     """The kernel path inside gae_select must agree with the jnp path."""
-    from repro.core.gae import fit_pca_basis, gae_select
+    from repro.core.gae import fit_pca_basis, gae_correct, gae_select
     ks = jax.random.split(KEY, 2)
     r = jax.random.normal(ks[0], (50, 40), jnp.float32) * 0.1
     basis = fit_pca_basis(r)
     a = gae_select(r, basis, tau=0.05, bin_size=0.01, use_kernel=False)
     b = gae_select(r, basis, tau=0.05, bin_size=0.01, use_kernel=True)
     np.testing.assert_array_equal(np.asarray(a.m), np.asarray(b.m))
-    np.testing.assert_allclose(np.asarray(a.corrected), np.asarray(b.corrected),
+    a_corrected, _ = gae_correct(r, basis, a, 0.01)
+    b_corrected, _ = gae_correct(r, basis, b, 0.01)
+    np.testing.assert_allclose(np.asarray(a_corrected), np.asarray(b_corrected),
                                atol=1e-5, rtol=1e-5)
 
 

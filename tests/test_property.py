@@ -65,14 +65,16 @@ def test_one_shot_selection_matches_algorithm1(data):
     bin_size = data.draw(st.floats(1e-3, 0.05))
     basis = np.asarray(gae.fit_pca_basis(jnp.asarray(x - x_r)))
     ref_out, ref_ms = gae.gae_reference_loop(x, x_r, basis, tau, bin_size)
-    sel = gae.gae_select(jnp.asarray(x - x_r), jnp.asarray(basis), tau, bin_size)
+    r, u = jnp.asarray(x - x_r), jnp.asarray(basis)
+    sel = gae.gae_select(r, u, tau, bin_size)
+    _, sel_err = gae.gae_correct(r, u, sel, bin_size)
     # same minimal M wherever Algorithm 1 terminated within D
     m = np.asarray(sel.m)
     for i in range(n):
         if ref_ms[i] < d:
             assert m[i] == ref_ms[i], (i, m[i], ref_ms[i])
     ref_err = np.linalg.norm(x - ref_out, axis=1)
-    sel_err = np.asarray(sel.err)
+    sel_err = np.asarray(sel_err)
     np.testing.assert_allclose(sel_err, ref_err, atol=1e-4)
 
 

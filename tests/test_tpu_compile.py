@@ -178,10 +178,15 @@ def _gae_stripe(dataset: str) -> tuple[int, int]:
 
 @pytest.mark.parametrize("dataset", ["s3d", "e3sm", "xgc"])   # D=80/256/1521
 def test_gae_select_compiles(one_chip, dataset):
+    """The selection fits one chip and moves its coefficients through its
+    sort: no gather, which the TPU lowers as one per element (a ``kCustom``
+    fusion fed by bit-packed indices)."""
     n, d = _gae_stripe(dataset)
     scalar = _spec(one_chip, ())
-    _compile(gae.gae_select, _spec(one_chip, (n, d)),
-             _spec(one_chip, (d, d)), scalar, scalar)
+    text = _compile(gae.gae_select, _spec(one_chip, (n, d)),
+                    _spec(one_chip, (d, d)), scalar, scalar).as_text()
+    assert "gather(" not in text
+    assert "kind=kCustom" not in text
 
 
 @pytest.mark.parametrize("dataset", ["s3d", "e3sm", "xgc"])   # D=80/256/1521
